@@ -35,6 +35,7 @@ def main() -> None:
     args = ap.parse_args()
 
     print("name,us_per_call,derived")
+    failed = []
     for mod in MODULES:
         if args.only and args.only not in mod.NAME:
             continue
@@ -44,12 +45,16 @@ def main() -> None:
             derived = mod.validate(rows) if hasattr(mod, "validate") else {}
             save_rows(mod.NAME, rows)
         except Exception as e:                      # noqa: BLE001
+            # Print the row so the remaining modules still run, then
+            # fail the whole run below: an error is never a result.
             derived = {"error": f"{type(e).__name__}: {e}"}
-            rows = []
+            failed.append(mod.NAME)
         us = (time.time() - t0) * 1e6
         print(f"{mod.NAME},{us:.0f},"
               f"\"{json.dumps(derived, default=str)}\"")
         sys.stdout.flush()
+    if failed:
+        sys.exit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -60,6 +60,13 @@ def activation_sharding(batch_axes: tuple, model_axis: str = "model",
          _SEQ_SHARD, _MOE_TOKEN_PARALLEL, _MESH, _EXACT) = prev
 
 
+def installed_mesh():
+    """The mesh a serving engine installed for this trace, or None.
+    Pallas kernels read it: GSPMD cannot partition a Mosaic call, so
+    under a mesh they run inside ``shard_map`` (see kernels/ops.py)."""
+    return _MESH
+
+
 def moe_a2a_mesh():
     """(mesh, expert_axis) when the shard_map a2a MoE should be used
     (inference under an installed mesh), else None."""

@@ -24,7 +24,9 @@ class MeshPlan:
     global_batch: int
 
     def make(self):
-        return jax.make_mesh(self.shape, self.axes)
+        from jax.sharding import AxisType
+        return jax.make_mesh(self.shape, self.axes,
+                             axis_types=(AxisType.Auto,) * len(self.axes))
 
 
 def plan_after_failure(current_shape: tuple, axes: tuple,

@@ -11,10 +11,15 @@ step, so the weights stream HBM→VMEM for exactly the adapters used, no
 materialised (B, din, r) gather. The shrink and expand matmuls fuse in
 one kernel invocation (the rank-r intermediate never leaves VMEM).
 
-Grid: (B, dout_tiles). Blocks: x (1, din), A (din, r), B (r, T_out),
-y (1, T_out). VMEM at din=6144, r=128, T_out=512: ~3.3 MB — comfortably
-under the ~16 MB/core budget; din and dout tiles are multiples of 128
-for MXU alignment (pad at the ops.py wrapper if needed).
+Grid: (B, dout_tiles). x and y carry a unit middle axis, (B, 1, din)
+and (B, 1, dout), so a one-token block (1, 1, din) keeps its last two
+dims equal to the array's — Mosaic accepts a block whose trailing dims
+are (8, 128) multiples or the full array dims, and a (1, din) row block
+of a (B, din) array is neither. Blocks: x (1, 1, din), A (din, r),
+B (r, T_out), y (1, 1, T_out). VMEM at din=6144, r=128, T_out=512:
+~3.3 MB — comfortably under the ~16 MB/core budget; din and dout tiles
+are multiples of 128 for MXU alignment (pad at the ops.py wrapper if
+needed).
 """
 from __future__ import annotations
 
@@ -27,12 +32,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _bgmv_kernel(idx_ref, x_ref, a_ref, b_ref, o_ref):
-    # x: (1, din); a: (1, din, r); b: (1, r, T_out); o: (1, T_out)
-    t = jnp.dot(x_ref[...], a_ref[0],
+    # x: (1, 1, din); a: (1, din, r); b: (1, r, T_out); o: (1, 1, T_out)
+    t = jnp.dot(x_ref[0], a_ref[0],
                 preferred_element_type=jnp.float32)      # (1, r)
-    o_ref[...] = jnp.dot(t.astype(b_ref.dtype), b_ref[0],
-                         preferred_element_type=jnp.float32
-                         ).astype(o_ref.dtype)
+    o_ref[0] = jnp.dot(t.astype(b_ref.dtype), b_ref[0],
+                       preferred_element_type=jnp.float32
+                       ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("out_tile", "interpret"))
@@ -47,7 +52,7 @@ def bgmv(x: jax.Array, A: jax.Array, B: jax.Array, idx: jax.Array,
     grid = (Bt, dout // out_tile)
 
     def x_map(b, j, idx_ref):
-        return b, 0
+        return b, 0, 0
 
     def a_map(b, j, idx_ref):
         return idx_ref[b], 0, 0
@@ -56,7 +61,7 @@ def bgmv(x: jax.Array, A: jax.Array, B: jax.Array, idx: jax.Array,
         return idx_ref[b], 0, j
 
     def o_map(b, j, idx_ref):
-        return b, j
+        return b, 0, j
 
     return pl.pallas_call(
         _bgmv_kernel,
@@ -64,12 +69,12 @@ def bgmv(x: jax.Array, A: jax.Array, B: jax.Array, idx: jax.Array,
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, din), x_map),
+                pl.BlockSpec((1, 1, din), x_map),
                 pl.BlockSpec((1, din, r), a_map),
                 pl.BlockSpec((1, r, out_tile), b_map),
             ],
-            out_specs=pl.BlockSpec((1, out_tile), o_map),
+            out_specs=pl.BlockSpec((1, 1, out_tile), o_map),
         ),
-        out_shape=jax.ShapeDtypeStruct((Bt, dout), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((Bt, 1, dout), x.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), x, A, B)
+    )(idx.astype(jnp.int32), x[:, None, :], A, B)[:, 0, :]

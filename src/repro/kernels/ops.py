@@ -13,7 +13,9 @@ through the bgmv kernel, batched prefill (S > 1, one contiguous run of
 S tokens per request) through the sgmv kernel with tile-aligned
 segments, and the pure-jnp einsum stays available as the CPU/oracle
 fallback. Backend choice is a static Python string resolved once per
-engine, so jit caches stay coherent.
+engine, so jit caches stay coherent. Under a mesh-sharded engine every
+Pallas call runs inside ``shard_map`` (``_on_mesh``): GSPMD cannot
+partition a Mosaic kernel.
 """
 from __future__ import annotations
 
@@ -23,6 +25,9 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from repro.distributed.act_sharding import installed_mesh
 
 from . import ref
 from .bgmv import bgmv as _bgmv_pallas
@@ -227,6 +232,26 @@ def _lora_sgmv_uniform(x, A, B, idx, tile, interpret):
     return y.reshape(Bt, S_pad, -1)[:, :S, :dout0]
 
 
+def _on_mesh(kernel):
+    """Run a Pallas call under the serving mesh the engine installed.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so under a mesh the call runs inside
+    ``shard_map`` with every operand replicated: each device gathers
+    its inputs and computes the whole result in single-device order,
+    which keeps the mesh engine's exact-reductions contract. Without a
+    mesh the kernel is called as is."""
+    mesh = installed_mesh()
+    if mesh is None:
+        return kernel
+
+    def run(*args):
+        return jax.shard_map(kernel, mesh=mesh,
+                             in_specs=tuple(P() for _ in args),
+                             out_specs=P(), check_vma=False)(*args)
+    return run
+
+
 def lora_delta_kernel(x, A, B, idx, *, scale: float = 1.0,
                       tile: int = 128, interpret: bool | None = None):
     """Multi-adapter LoRA delta through the Pallas kernels.
@@ -238,9 +263,11 @@ def lora_delta_kernel(x, A, B, idx, *, scale: float = 1.0,
     """
     interpret = (not on_tpu()) if interpret is None else interpret
     if x.shape[1] == 1:
-        y = _lora_bgmv_tokens(x[:, 0], A, B, idx, interpret)[:, None]
+        y = _on_mesh(lambda x, A, B, idx: _lora_bgmv_tokens(
+            x[:, 0], A, B, idx, interpret)[:, None])(x, A, B, idx)
     else:
-        y = _lora_sgmv_uniform(x, A, B, idx, tile, interpret)
+        y = _on_mesh(lambda x, A, B, idx: _lora_sgmv_uniform(
+            x, A, B, idx, tile, interpret))(x, A, B, idx)
     return (scale * y).astype(x.dtype)
 
 
@@ -253,5 +280,5 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
         return ref.paged_attention_ref(q, k_pages, v_pages, page_table,
                                        lengths)
     interpret = (not on_tpu()) if interpret is None else interpret
-    return _paged_pallas(q, k_pages, v_pages, page_table, lengths,
-                         interpret=interpret)
+    return _on_mesh(functools.partial(_paged_pallas, interpret=interpret))(
+        q, k_pages, v_pages, page_table, lengths)

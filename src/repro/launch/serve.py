@@ -31,6 +31,10 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.backend == "engine":
+        from repro.launch.device import init_device
+        stamp = init_device()
+        print("device:", json.dumps(stamp))
+
         import jax
         import jax.numpy as jnp
         from repro.configs import get_config

@@ -56,8 +56,6 @@ def moe_block_a2a(x: jax.Array, router_w: jax.Array,
 
     Requires B % n_shards == 0 and E % n_shards == 0.
     Returns (out (B,S,D), aux scalar)."""
-    from jax.experimental.shard_map import shard_map
-
     E = router_w.shape[-1]
     n_shards = mesh.shape[expert_axis]
     assert E % n_shards == 0, (E, n_shards)
@@ -93,13 +91,13 @@ def moe_block_a2a(x: jax.Array, router_w: jax.Array,
         return (y.reshape(Bl, S, D),
                 jax.lax.pmean(aux, expert_axis))
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(expert_axis, None, None), P(None, None),
                   P(expert_axis, None, None),
                   P(expert_axis, None, None),
                   P(expert_axis, None, None)),
         out_specs=(P(expert_axis, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, router_w, w_gate, w_up, w_down)
     return out, aux

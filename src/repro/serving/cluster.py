@@ -324,6 +324,8 @@ class EngineCluster:
     """
 
     def __init__(self, cfg, params, ecfg=None, ccfg=None):
+        from repro.launch.mesh import replica_devices
+
         from .engine import AdapterCatalog, EngineConfig
         from .systems import build_engine
 
@@ -348,10 +350,13 @@ class EngineCluster:
                                       self.ecfg.r_max,
                                       seed=self.ccfg.seed)
         self._clock = _SharedClock()
+        # Replica i owns its own chip (or mesh slice).
         self.engines = [
             build_engine(self.ccfg.system, cfg, params, self.ecfg,
-                         catalog=self.catalog, clock=self._clock)
-            for _ in range(self.ccfg.n_engines)]
+                         catalog=self.catalog, clock=self._clock,
+                         devices=devs)
+            for devs in replica_devices(self.ccfg.n_engines,
+                                        self.ecfg.mesh_shape)]
         self.router = Router(self.ccfg.policy, self.ccfg.n_engines,
                              self.ccfg.affinity_overload_factor,
                              seed=self.ccfg.seed)

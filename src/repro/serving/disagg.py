@@ -219,6 +219,8 @@ class DisaggCluster:
     """
 
     def __init__(self, cfg, params, ecfg=None, dcfg=None):
+        from repro.launch.mesh import replica_devices
+
         from .engine import AdapterCatalog, EngineConfig
         from .systems import build_engine
 
@@ -232,14 +234,19 @@ class DisaggCluster:
         self._clock = _SharedClock()
         prefill_ecfg = dataclasses.replace(
             self.ecfg, max_horizon=1, pipeline_readback=False)
+        # Every replica owns its own chip (or mesh slice).
+        devs = replica_devices(self.dcfg.n_prefill + self.dcfg.n_decode,
+                               self.ecfg.mesh_shape)
         self.prefill = [
             build_engine(self.dcfg.system, cfg, params, prefill_ecfg,
-                         catalog=self.catalog, clock=self._clock)
-            for _ in range(self.dcfg.n_prefill)]
+                         catalog=self.catalog, clock=self._clock,
+                         devices=devs[i])
+            for i in range(self.dcfg.n_prefill)]
         self.decode = [
             build_engine(self.dcfg.system, cfg, params, self.ecfg,
-                         catalog=self.catalog, clock=self._clock)
-            for _ in range(self.dcfg.n_decode)]
+                         catalog=self.catalog, clock=self._clock,
+                         devices=devs[self.dcfg.n_prefill + i])
+            for i in range(self.dcfg.n_decode)]
         self.router = Router(self.dcfg.prefill_policy,
                              self.dcfg.n_prefill,
                              self.dcfg.affinity_overload_factor,

@@ -234,7 +234,9 @@ class AdapterCatalog:
     Holds the adapter weights ("host memory" in the paper) and the
     AdapterInfo metadata the control plane prices residency with. One
     catalog serves N engines: replicas keep per-device slot buffers but
-    never duplicate the host-side weights (DESIGN §3).
+    never duplicate the host-side weights (DESIGN §3). The weights are
+    numpy arrays in host RAM, so a cache fill is a real host-to-device
+    transfer and catalog adapters take no device memory.
     """
 
     def __init__(self, cfg: ModelConfig, n_adapters: int, r_max: int,
@@ -246,9 +248,9 @@ class AdapterCatalog:
                       for i in range(n_adapters)]
         keys = jax.random.split(key, n_adapters)
         self.weights = {
-            aid: random_lora_weights(keys[aid], self.ranks[aid], r_max,
-                                     cfg.n_layers, cfg.d_model,
-                                     cfg.q_dim, cfg.kv_dim)
+            aid: jax.tree.map(np.asarray, random_lora_weights(
+                keys[aid], self.ranks[aid], r_max, cfg.n_layers,
+                cfg.d_model, cfg.q_dim, cfg.kv_dim))
             for aid in range(n_adapters)}
         kv_tok = 2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * 2
         lora_bytes = {aid: sum(
@@ -276,7 +278,8 @@ class ChameleonEngine:
                  scheduler_cls=ChameleonScheduler, cache_enabled=True,
                  catalog: AdapterCatalog | None = None,
                  clock: Optional[Callable[[], float]] = None,
-                 draft: Optional[tuple] = None):
+                 draft: Optional[tuple] = None,
+                 devices: Optional[list] = None):
         self.cfg = cfg
         self.params = params
         self.ecfg = ecfg or EngineConfig()
@@ -284,27 +287,36 @@ class ChameleonEngine:
         key = jax.random.PRNGKey(e.seed)
 
         # --- serving mesh (DESIGN §4): one engine across N devices ---
+        # ``devices`` pins this engine to an explicit device list (a
+        # cluster hands every replica its own chip or mesh slice); None
+        # keeps the default placement. A single-device pin commits the
+        # resident state (weights, slot arena, KV, batch state) to that
+        # device, so every jit follows its committed inputs there.
         self.mesh = None
         self.plan: Optional[ShardPlan] = None
+        self._device = None
         self._collective = False      # mesh>1: COLLECTIVE_METER armed
         if e.mesh_shape is not None:
             d, m = e.mesh_shape
-            self.mesh = make_serving_mesh(d * m, m)
+            self.mesh = make_serving_mesh(d * m, m, devices=devices)
             self.plan = ShardPlan(self.mesh, cfg)
             # Weights land sharded over "model" once, up front —
             # resident, never re-gathered per step.
             self._params_sh = self.plan.params(params)
             self.params = params = jax.device_put(params, self._params_sh)
             self._collective = self.mesh.size > 1
+        elif devices is not None:
+            self._device = devices[0]
+            self.params = params = self._pin(params)
 
         # --- LoRA adapter catalog (host-side weights = "host memory") ---
         self.catalog = catalog or AdapterCatalog(cfg, e.n_adapters,
                                                  e.r_max, seed=e.seed)
         self.host_adapters = self.catalog.weights
         # Device adapter-slot buffers (per replica).
-        self.lora = init_lora_slots(key, e.n_lora_slots, cfg.n_layers,
-                                    cfg.d_model, cfg.q_dim, cfg.kv_dim,
-                                    self.catalog.r_max)
+        self.lora = self._pin(init_lora_slots(
+            key, e.n_lora_slots, cfg.n_layers, cfg.d_model, cfg.q_dim,
+            cfg.kv_dim, self.catalog.r_max))
         # Sharded slot arena: A replicated, B dout over "model" (the
         # LoRA delta adds to the sharded projection output without a
         # reshard — S-LoRA's TP partition strategy). Host adapter
@@ -388,8 +400,8 @@ class ChameleonEngine:
                 ds = self.mesh.shape["data"]
                 self.n_pages = -(-self.n_pages // ds) * ds
             self.pages_per_slot = -(-e.max_len // ps)
-            self.kv_pages = api.init_paged_serve_state(
-                cfg, self.n_pages, ps, jnp.float32)
+            self.kv_pages = self._pin(api.init_paged_serve_state(
+                cfg, self.n_pages, ps, jnp.float32))
             if self.plan is not None:
                 kvp = self.plan.kv_pages(self.kv_pages[0].shape)
                 self._kv_sh = (kvp, kvp)
@@ -401,8 +413,8 @@ class ChameleonEngine:
             self.free_pages = list(range(self.n_pages - 1, 0, -1))
             self.kv = None
         else:
-            self.kv = api.init_serve_state(cfg, e.max_slots, e.max_len,
-                                           jnp.float32)
+            self.kv = self._pin(api.init_serve_state(
+                cfg, e.max_slots, e.max_len, jnp.float32))
             if self.plan is not None:
                 kvd = self.plan.kv_dense(self.kv[0].shape)
                 self._kv_sh = (kvd, kvd)
@@ -421,10 +433,10 @@ class ChameleonEngine:
         self.prefix_lookup_tokens = 0
         self.n_prefix_hits = 0          # placements with a nonzero match
         self.n_cow_forks = 0
-        self.tokens = jnp.zeros((e.max_slots, 1), jnp.int32)
-        self.cache_len = jnp.zeros((e.max_slots,), jnp.int32)
+        self.tokens = self._pin(jnp.zeros((e.max_slots, 1), jnp.int32))
+        self.cache_len = self._pin(jnp.zeros((e.max_slots,), jnp.int32))
         self.active = np.zeros((e.max_slots,), bool)
-        self.adapter_slot = jnp.zeros((e.max_slots,), jnp.int32)
+        self.adapter_slot = self._pin(jnp.zeros((e.max_slots,), jnp.int32))
         if self.plan is not None:
             # Batch state over "data". ``_batch_sh(ndim)`` reuses the
             # fitted row spec so a max_slots that doesn't divide the
@@ -621,21 +633,28 @@ class ChameleonEngine:
             draft_params = api.init_params(
                 draft_cfg, jax.random.PRNGKey(e.seed + 1))
         self.draft_cfg = draft_cfg
-        self.draft_params = draft_params
+        self.draft_params = self._pin(draft_params)
         # The draft KV is a dense slab outside the paged pool: the
         # draft is small and adapter-free, so its cache is priced as
         # part of the (constant) speculation overhead, not as
         # per-request pool occupancy. "Freeing" a slot's draft KV is
         # bookkeeping: _draft_len drops to 0 and the slab rows are
         # rewritten by the next occupant's catch-up.
-        self.draft_kv = api.init_serve_state(
-            draft_cfg, e.max_slots, e.max_len, jnp.float32)
+        self.draft_kv = self._pin(api.init_serve_state(
+            draft_cfg, e.max_slots, e.max_len, jnp.float32))
         # Tokens of the *target* cache the draft cache mirrors, per
         # slot (host truth — the lazy catch-up syncs the gap).
         self._draft_len = np.zeros(e.max_slots, np.int64)
         self.spec = True
 
     # --------------------------------------------- sharded data plane
+    def _pin(self, tree):
+        """Commit ``tree`` to this engine's pinned device (no-op when
+        the engine is unpinned)."""
+        if self._device is None:
+            return tree
+        return jax.device_put(tree, self._device)
+
     def _batch_sh(self, ndim: int):
         """NamedSharding for (max_slots, ...) batch-state tensors."""
         return self.plan.named(

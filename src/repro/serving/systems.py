@@ -116,14 +116,15 @@ ENGINE_SYSTEMS = {
 
 
 def build_engine(system: str, cfg, params, ecfg=None, catalog=None,
-                 clock=None):
+                 clock=None, devices=None):
     """Assemble one real-engine replica for ``system``.
 
     Mirrors ``build_node`` for the JAX data plane: same policy matrix,
     but the returned object runs jit'd prefill/decode on real tokens.
     ``catalog`` (shared AdapterCatalog) and ``clock`` let a cluster
     deduplicate host adapter weights and share a timebase across
-    replicas.
+    replicas; ``devices`` pins the replica to its own chip or mesh
+    slice (see ``launch.mesh.replica_devices``).
     """
     from .engine import ChameleonEngine
     if system not in ENGINE_SYSTEMS:
@@ -132,7 +133,7 @@ def build_engine(system: str, cfg, params, ecfg=None, catalog=None,
     sched_cls, cache_enabled = ENGINE_SYSTEMS[system]
     return ChameleonEngine(cfg, params, ecfg, scheduler_cls=sched_cls,
                            cache_enabled=cache_enabled, catalog=catalog,
-                           clock=clock)
+                           clock=clock, devices=devices)
 
 
 # ------------------------------------------------------------------

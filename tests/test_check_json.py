@@ -77,3 +77,27 @@ class TestMain:
         garbled = tmp_path / "garbled.json"
         garbled.write_text("{not json")
         assert main([str(garbled)]) == 1
+
+
+class TestRunFailsLoudly:
+    """``benchmarks.run`` prints a failing module's row, keeps going,
+    then exits non-zero: an error is never recorded as a result."""
+
+    def test_module_error_fails_the_run(self, monkeypatch, capsys):
+        import types
+
+        from benchmarks import run
+
+        def boom(quick):
+            raise RuntimeError("kaput")
+
+        ok = types.SimpleNamespace(NAME="ok_mod", run=lambda quick: [])
+        bad = types.SimpleNamespace(NAME="bad_mod", run=boom)
+        monkeypatch.setattr(run, "MODULES", (bad, ok))
+        monkeypatch.setattr(run, "save_rows", lambda name, rows: None)
+        monkeypatch.setattr("sys.argv", ["run"])
+        with pytest.raises(SystemExit) as exc:
+            run.main()
+        assert exc.value.code not in (0, None)
+        out = capsys.readouterr().out
+        assert "bad_mod," in out and "kaput" in out and "ok_mod," in out

@@ -74,6 +74,21 @@ class TestRouter:
         assert moved < 100      # ~1/5 expected; far below full remap
 
 
+class TestReplicaDevices:
+    """Replica i of a cluster owns devices [i*k, (i+1)*k)."""
+
+    def test_one_device_each(self, monkeypatch):
+        from repro.launch import mesh
+        monkeypatch.setattr(mesh.jax, "devices", lambda: list("abcd"))
+        assert mesh.replica_devices(4) == [["a"], ["b"], ["c"], ["d"]]
+        assert mesh.replica_devices(2, (2, 1)) == [["a", "b"], ["c", "d"]]
+
+    def test_too_few_devices_share_the_default(self, monkeypatch):
+        from repro.launch import mesh
+        monkeypatch.setattr(mesh.jax, "devices", lambda: ["a"])
+        assert mesh.replica_devices(3) == [None, None, None]
+
+
 # ------------------------------------------------------------------
 # Real-engine cluster e2e
 # ------------------------------------------------------------------
@@ -134,6 +149,36 @@ class TestEngineCluster:
         for e in cluster.engines:
             assert e.catalog is cluster.catalog
             assert e.host_adapters is cluster.catalog.weights
+
+    def test_catalog_weights_live_in_host_memory(self, small_model):
+        """Catalog adapters are numpy in host RAM, so a cache fill is a
+        host-to-device copy and idle adapters take no device memory."""
+        cfg, _ = small_model
+        cat = AdapterCatalog(cfg, 3, 8)
+        leaves = jax.tree.leaves(cat.weights)
+        assert leaves and all(isinstance(x, np.ndarray) for x in leaves)
+
+    def test_pinned_engine_matches_default_placement(self, small_model):
+        """An engine pinned to an explicit device keeps its resident
+        state committed there and decodes the same tokens as one with
+        the default placement."""
+        cfg, params = small_model
+        dev = jax.devices()[-1]
+        specs = request_specs(6, seed=5)
+        outs = []
+        for devices in (None, [dev]):
+            eng = ChameleonEngine(cfg, params, EngineConfig(**ECFG),
+                                  devices=devices)
+            reqs = [Request(input_len=i, output_len=o, adapter_id=a)
+                    for i, o, a in specs]
+            for r in reqs:
+                eng.submit(r)
+            eng.drain()
+            outs.append([eng.outputs[r.req_id] for r in reqs])
+        assert outs[0] == outs[1]
+        assert eng.params["embed/tok"].devices() == {dev}
+        assert eng.kv_pages[0].devices() == {dev}
+        assert jax.tree.leaves(eng.lora)[0].devices() == {dev}
 
     def test_affinity_routes_to_resident_replica(self, small_model):
         """Once adapter 0 is resident on the replica that served it,

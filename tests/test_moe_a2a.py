@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.models.moe import moe_block, moe_block_gather
 from repro.models.moe_shard_map import moe_block_a2a
@@ -23,7 +24,8 @@ def operands():
 
 def test_a2a_matches_einsum_dispatch(operands):
     x, rw, wg, wu, wd = operands
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with mesh:
         y_ref, _ = moe_block(x, rw, wg, wu, wd, top_k=2,
                              capacity_factor=8.0, group_size=64)
@@ -37,7 +39,8 @@ def test_a2a_matches_einsum_dispatch(operands):
 def test_a2a_dropless_decode_matches_gather(operands):
     x, rw, wg, wu, wd = operands
     x1 = x[:, :1]                      # decode: S == 1
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     E, K = 8, 2
     with mesh:
         y_ref, _ = moe_block_gather(x1, rw, wg, wu, wd, top_k=K)

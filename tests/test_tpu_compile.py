@@ -1,0 +1,105 @@
+"""The serving path's Pallas kernels compile for a TPU v5e chip.
+
+Each case lowers one kernel through ``repro.kernels.ops`` at
+internlm2-1.8b widths (d 2048, q 2048, kv 1024, head_dim 128, 16/8
+heads so G = 2, r_max 32, page 16, 8 rows) and compiles it for one chip
+of a *described* ``v5e:2x2`` topology — the TPU compiler runs here, no
+chip is attached. Interpret mode cannot see what the chip's compiler
+refuses (block shapes off the (8, 128) tiling, VMEM over budget), so
+these are the guard for that. The dtypes are the engine's: float32
+activations and KV, bfloat16 adapter-slot arenas.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may hold the TPU library, and every test
+worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+
+CFG = get_config("internlm2-1.8b")
+R_MAX = 32
+N_SLOTS = 8
+ROWS = 8
+PAGE = 16
+MAX_LEN = 1024
+N_PAGES = 1025
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip lands in the persistent cache but
+    # cannot be read back without one: keep the cache off meanwhile.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# (din, dout) of the adapted projections: q/o are d -> d, k/v d -> kv.
+PROJ = {"q": (CFG.d_model, CFG.q_dim), "k": (CFG.d_model, CFG.kv_dim),
+        "o": (CFG.q_dim, CFG.d_model)}
+
+
+@pytest.mark.parametrize("proj", sorted(PROJ))
+def test_bgmv_decode_compiles(one_chip, proj):
+    din, dout = PROJ[proj]
+    args = (_spec((ROWS, 1, din), jnp.float32, one_chip),
+            _spec((N_SLOTS, din, R_MAX), jnp.bfloat16, one_chip),
+            _spec((N_SLOTS, R_MAX, dout), jnp.bfloat16, one_chip),
+            _spec((ROWS,), jnp.int32, one_chip))
+    txt = _compiled_text(
+        lambda x, a, b, i: ops.lora_delta_kernel(x, a, b, i,
+                                                 interpret=False), *args)
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("B,S", [(1, 512), (4, 128)])
+def test_sgmv_prefill_compiles(one_chip, B, S):
+    din, dout = PROJ["q"]
+    args = (_spec((B, S, din), jnp.float32, one_chip),
+            _spec((N_SLOTS, din, R_MAX), jnp.bfloat16, one_chip),
+            _spec((N_SLOTS, R_MAX, dout), jnp.bfloat16, one_chip),
+            _spec((B,), jnp.int32, one_chip))
+    txt = _compiled_text(
+        lambda x, a, b, i: ops.lora_delta_kernel(x, a, b, i,
+                                                 interpret=False), *args)
+    assert "tpu_custom_call" in txt
+
+
+def test_paged_attention_compiles(one_chip):
+    Kh, G, dh = CFG.n_kv_heads, CFG.n_heads // CFG.n_kv_heads, CFG.head_dim
+    pool = (N_PAGES, PAGE, Kh, dh)
+    args = (_spec((ROWS, Kh, G, dh), jnp.float32, one_chip),
+            _spec(pool, jnp.float32, one_chip),
+            _spec(pool, jnp.float32, one_chip),
+            _spec((ROWS, MAX_LEN // PAGE), jnp.int32, one_chip),
+            _spec((ROWS,), jnp.int32, one_chip))
+    txt = _compiled_text(
+        lambda q, k, v, pt, ln: ops.paged_attention(
+            q, k, v, pt, ln, prefer_kernel=True, interpret=False), *args)
+    assert "tpu_custom_call" in txt
