@@ -42,7 +42,7 @@ REQUIRED_VALIDATED = {
         "prefix_reduces_p99_ttft"},
     "fig17_scalability_sharded_engine": {
         "all_completed", "tokens_identical", "mesh_shape", "n_devices",
-        "throughput_ratio_mesh_over_single", "collective_frac"},
+        "throughput_ratio_mesh_over_single"},
     "gateway": {"all_completed", "fair_tenant_p99_improves"},
     "disagg_interference": {"all_completed", "tokens_identical",
                             "handoffs", "prefill_util", "decode_util"},

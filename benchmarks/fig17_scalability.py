@@ -188,8 +188,6 @@ def run_real_engine(n_requests: int = 24, seed: int = 0,
             "prefix_hit_rate": eng.stats()["prefix_hit_rate"],
             "tokens_identical_to_single":
                 tokens_by_mode["single"] == streamed,
-            "collective_frac": ss.get("collective_frac", 0.0),
-            "collective_dispatches": ss.get("collective_dispatches", 0),
             "per_shard_pages_used": ss.get("per_shard_pages_used", []),
             "per_shard_lora_slot_bytes":
                 ss.get("per_shard_lora_slot_bytes", 0),
@@ -213,7 +211,6 @@ def validate_real_engine(rows) -> dict:
         "n_devices": mesh["n_devices"],
         "throughput_ratio_mesh_over_single": round(
             mesh["tokens_per_s"] / max(single["tokens_per_s"], 1e-9), 3),
-        "collective_frac": mesh["collective_frac"],
         "prefix_hit_rate_mesh": mesh["prefix_hit_rate"],
     }
 

@@ -77,4 +77,5 @@ def bgmv(x: jax.Array, A: jax.Array, B: jax.Array, idx: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((Bt, 1, dout), x.dtype),
         interpret=interpret,
+        name="bgmv",
     )(idx.astype(jnp.int32), x[:, None, :], A, B)[:, 0, :]

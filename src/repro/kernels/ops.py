@@ -49,7 +49,8 @@ class DispatchMeter:
 
     The serving engine ticks the meter once per device dispatch it
     launches on the decode path and wraps its device→host token reads
-    in ``sync()``. ``benchmarks/decode_hotloop.py`` reads the meter to
+    in ``sync()`` (the same reads are its ``engine.readback`` spans
+    while a profiler capture runs, ``serving/spans.py``). ``benchmarks/decode_hotloop.py`` reads the meter to
     report dispatches/token and the host-sync fraction — the two
     numbers the fused device-resident loop exists to shrink. A plain
     counter + accumulator: the per-step cost is one int add, so the
@@ -98,36 +99,6 @@ class DispatchMeter:
 
 #: Process-wide meter the engine step loops tick (reset by benchmarks).
 DISPATCH_METER = DispatchMeter()
-
-
-class CollectiveMeter(DispatchMeter):
-    """DISPATCH_METER-style probe for the sharded data plane.
-
-    A mesh>1 engine ticks this once per sharded dispatch and wraps the
-    blocking completion of that dispatch in ``sync()`` — on a sharded
-    program the dominant cost of that wait beyond single-device compute
-    is the GSPMD collectives (psum after row-parallel matmuls, page
-    all-gathers), so ``frac()`` reports the collective/wall time
-    fraction the per-device gauges in ``serving/metrics.py`` export.
-    ``reset()`` also restarts the wall clock the fraction is taken
-    over.
-    """
-
-    def __init__(self) -> None:
-        super().__init__()
-        self._t0 = time.perf_counter()
-
-    def reset(self) -> None:
-        super().reset()
-        self._t0 = time.perf_counter()
-
-    def frac(self) -> float:
-        wall = time.perf_counter() - self._t0
-        return (self.sync_seconds / wall) if wall > 0 else 0.0
-
-
-#: Process-wide probe for sharded (mesh>1) engine dispatches.
-COLLECTIVE_METER = CollectiveMeter()
 
 
 def resolve_lora_backend(backend: str | None) -> str:

@@ -108,4 +108,5 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Kh, G, dh), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), q, kp, vp)

@@ -61,6 +61,7 @@ def sgmv(x: jax.Array, A: jax.Array, B: jax.Array, tile_slot: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((T, dout), x.dtype),
         interpret=interpret,
+        name="sgmv",
     )(tile_slot.astype(jnp.int32), x, A, B)
 
 

@@ -108,8 +108,7 @@ from repro.core import (AdapterCache, AdapterInfo, CacheStats,
                         PrefixCache, QueuedRequestPrefetcher, Request,
                         RequestState, SamplingParams)
 from repro.distributed.act_sharding import activation_sharding
-from repro.kernels.ops import (COLLECTIVE_METER, DISPATCH_METER,
-                               resolve_lora_backend)
+from repro.kernels.ops import DISPATCH_METER, resolve_lora_backend
 from repro.launch.mesh import make_serving_mesh
 from repro.models import api
 from repro.models.base import ModelConfig
@@ -118,6 +117,7 @@ from repro.models.lora_apply import (init_lora_slots, random_lora_weights,
 from repro.serving.handles import RequestHandle, prepare_request
 from repro.serving.metrics import RequestRecord, RunMetrics
 from repro.serving.shard_plan import ShardPlan
+from repro.serving.spans import NO_SPANS, NULL_SPAN, SPANS
 
 
 @dataclass
@@ -295,7 +295,6 @@ class ChameleonEngine:
         self.mesh = None
         self.plan: Optional[ShardPlan] = None
         self._device = None
-        self._collective = False      # mesh>1: COLLECTIVE_METER armed
         if e.mesh_shape is not None:
             d, m = e.mesh_shape
             self.mesh = make_serving_mesh(d * m, m, devices=devices)
@@ -304,7 +303,6 @@ class ChameleonEngine:
             # resident, never re-gathered per step.
             self._params_sh = self.plan.params(params)
             self.params = params = jax.device_put(params, self._params_sh)
-            self._collective = self.mesh.size > 1
         elif devices is not None:
             self._device = devices[0]
             self.params = params = self._pin(params)
@@ -452,6 +450,14 @@ class ChameleonEngine:
         self.slot_req: list[Optional[Request]] = [None] * e.max_slots
         self.t0 = time.monotonic()
         self._clock = clock
+        # Engine spans (``serving/spans.py``) while a profiler capture
+        # runs; an injected clock's times are not the capture's.
+        self.spans = SPANS if clock is None else NO_SPANS
+        self._horizon_k = 0                    # the step's decode K
+        self._prefill_span: dict[int, int] = {}   # req_id -> prefill span
+        # adapter_id -> (dispatch time, dispatching step, attributes) of
+        # an in-flight load, held while spans are on.
+        self._load_spans: dict[int, tuple] = {}
         self.completed: list[Request] = []
         self.records: list[RequestRecord] = []
         self.outputs: dict[int, list[int]] = {}
@@ -844,6 +850,11 @@ class ChameleonEngine:
                 f"cache_loading={sorted(self.cache.loading_ids())})")
         slot = self.free_slots.pop()
         self.slot_of[info.adapter_id] = slot
+        if self.spans.on:
+            self._load_spans[info.adapter_id] = (
+                time.monotonic(), self.spans.outermost(),
+                dict(adapter=info.adapter_id, rank=info.rank, slot=slot,
+                     bytes=info.size_bytes))
         self._lora_staging = write_adapter_to_slot(
             self._lora_staging, self.host_adapters[info.adapter_id], slot,
             shardings=self._adapter_w_sh)
@@ -865,6 +876,7 @@ class ChameleonEngine:
         else:
             jax.block_until_ready(self._lora_staging)
             self.lora = self._lora_staging
+            self._record_load(info.adapter_id)
             if delay:
                 time.sleep(delay)   # modeled H2D stall blocks the loop
 
@@ -889,6 +901,7 @@ class ChameleonEngine:
                 if not all(x.is_ready() for x in leaves):
                     break           # FIFO: later writes chain on this one
                 self.lora = staged
+                self._record_load(aid)
                 self._pending_loads[aid] = (None, (), t_ready)
             if now >= t_ready:
                 del self._pending_loads[aid]
@@ -904,7 +917,17 @@ class ChameleonEngine:
         self.lora = self._lora_staging
         for aid in list(self._pending_loads):
             del self._pending_loads[aid]
+            self._record_load(aid)
             self.cache.mark_ready(aid)
+
+    def _record_load(self, aid: int) -> None:
+        """An ``adapter.load`` span, from the write's dispatch to the
+        swap of its snapshot into the active arena."""
+        held = self._load_spans.pop(aid, None)
+        if held is not None:
+            t, parent, attrs = held
+            self.spans.record("adapter.load", t, time.monotonic(),
+                              parent=parent, **attrs)
 
     def _evict_adapter(self, info: AdapterInfo) -> None:
         slot = self.slot_of.pop(info.adapter_id)
@@ -912,6 +935,7 @@ class ChameleonEngine:
         # LOADING entries are never eviction candidates, so a pending
         # load here is unreachable; drop it anyway to stay consistent.
         self._pending_loads.pop(info.adapter_id, None)
+        self._load_spans.pop(info.adapter_id, None)
 
     # ------------------------------------------------------- jit'd steps
     # ``self._lora_backend`` is a resolved Python constant captured by
@@ -1146,10 +1170,37 @@ class ChameleonEngine:
         reservation or quota charges."""
         req.state = state
         req.finish_time = now
+        self._record_request_spans(req)
         if state is RequestState.CANCELLED:
             self.n_cancelled += 1
         else:
             self.n_expired += 1
+
+    def _record_request_spans(self, req: Request) -> None:
+        """A terminal request's ``request.queued`` (arrival to first
+        admission), ``request.prefill`` (admission to first token, under
+        the ``engine.prefill`` span that served it) and
+        ``request.decode`` (first token to last), from the times it
+        holds on the engine clock."""
+        parent = self._prefill_span.pop(req.req_id, None)
+        if not self.spans.on:
+            return
+        rid, t0 = req.req_id, self.t0
+        attrs = dict(adapter=req.adapter_id,
+                     rank=self.catalog.rank_of(req.adapter_id),
+                     prompt=req.input_len, tokens=req.generated,
+                     state=req.state.value, squashes=req.squash_count)
+        admitted, first = req.first_scheduled_time, req.first_token_time
+        self.spans.record(
+            "request.queued", t0 + req.arrival_time,
+            t0 + (admitted if admitted is not None else req.finish_time),
+            req_id=rid, adapter_wait=req.adapter_load_wait, **attrs)
+        if admitted is not None and first is not None:
+            self.spans.record("request.prefill", t0 + admitted, t0 + first,
+                              parent=parent, req_id=rid, **attrs)
+        if first is not None:
+            self.spans.record("request.decode", t0 + first,
+                              t0 + req.finish_time, req_id=rid, **attrs)
 
     # ------------------------------------------------------ token delivery
     def _record_token(self, req: Request, pos: int, tok: int,
@@ -1205,7 +1256,12 @@ class ChameleonEngine:
             if big:
                 reqs = [r for r in reqs
                         if r.input_len <= e.prefill_chunk_tokens]
-                self._start_chunked(big)
+                # Chunks write their own K/V: placement is the pages.
+                with self.spans.span("engine.kv_place") as sp:
+                    if sp:
+                        sp.set(pages=sum(self.pool.pages_for(r.input_len)
+                                         for r in big))
+                    self._start_chunked(big)
             if not reqs:
                 return
         if self.prefix is not None:
@@ -1239,57 +1295,59 @@ class ChameleonEngine:
             toks[i, :req.input_len] = self._prompt_tokens(req)
             last_pos[i] = req.input_len - 1
             lslots[i] = self.slot_of[req.adapter_id]
-        with self._act_scope():
-            logits, (k_new, v_new) = self._get_prefill_jit(B, S)(
-                self.params, self._prefill_lora(), jnp.asarray(toks),
-                jnp.asarray(lslots), jnp.asarray(last_pos), S)
-        if self._all_greedy(reqs):
-            first_toks = np.asarray(
-                jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        else:
-            first_toks = np.asarray(self._sample_jit(
-                logits, *self._sampling_arrays(reqs, B, first=True)))
+        with self._prefill_span_for(reqs, B, S):
+            with self._act_scope():
+                logits, (k_new, v_new) = self._get_prefill_jit(B, S)(
+                    self.params, self._prefill_lora(), jnp.asarray(toks),
+                    jnp.asarray(lslots), jnp.asarray(last_pos), S)
+            first_toks = self._first_tokens(logits, reqs, B)
         if self.paged:
             kp, vp = self.kv_pages
         else:
             k, v = self.kv
         now = self.now()
         ps = self.pool.page_size
-        for i, req in enumerate(reqs):
-            slot = free[i]
-            self.active[slot] = True
-            self.slot_req[slot] = req
-            L = req.input_len
-            if self.paged:
-                pages = self.slot_pages[slot]
-                for j in range(0, L, ps):
-                    pid = pages[j // ps]
-                    n = min(ps, L - j)
-                    kp = kp.at[:, pid, :n].set(k_new[:, i, j:j + n])
-                    vp = vp.at[:, pid, :n].set(v_new[:, i, j:j + n])
-            else:
-                k = k.at[:, slot, :L].set(k_new[:, i, :L])
-                v = v.at[:, slot, :L].set(v_new[:, i, :L])
-            first = int(first_toks[i])
-            self.tokens = self.tokens.at[slot, 0].set(first)
-            self.cache_len = self.cache_len.at[slot].set(L)
-            self.adapter_slot = self.adapter_slot.at[slot].set(
-                int(lslots[i]))
-            req.generated = 1
-            rid = req.req_id
-            if req.preserved_tokens:
-                # Squash survivor: restore the streamed prefix and its
-                # latency records; re-execution regenerates (and the
-                # handle ignores) positions the user already has.
-                self.outputs[rid] = list(req.preserved_tokens)
-                self._tbts[rid] = list(req.preserved_tbts)
-                if req.last_stream_time is not None:
-                    self._last_tok[rid] = req.last_stream_time
-            else:
-                self.outputs[rid] = []
-                self._tbts[rid] = []
-                req.first_token_time = now
-            self._record_token(req, 0, first, now)
+        # The eager page loop: each prompt's K/V into its pages, and its
+        # row seated with the first token.
+        with self.spans.span("engine.kv_place") as sp:
+            if sp:
+                sp.set(pages=sum(self.pool.pages_for(r.input_len)
+                                 for r in reqs) if self.paged else 0)
+            for i, req in enumerate(reqs):
+                slot = free[i]
+                self.active[slot] = True
+                self.slot_req[slot] = req
+                L = req.input_len
+                if self.paged:
+                    pages = self.slot_pages[slot]
+                    for j in range(0, L, ps):
+                        pid = pages[j // ps]
+                        n = min(ps, L - j)
+                        kp = kp.at[:, pid, :n].set(k_new[:, i, j:j + n])
+                        vp = vp.at[:, pid, :n].set(v_new[:, i, j:j + n])
+                else:
+                    k = k.at[:, slot, :L].set(k_new[:, i, :L])
+                    v = v.at[:, slot, :L].set(v_new[:, i, :L])
+                first = int(first_toks[i])
+                self.tokens = self.tokens.at[slot, 0].set(first)
+                self.cache_len = self.cache_len.at[slot].set(L)
+                self.adapter_slot = self.adapter_slot.at[slot].set(
+                    int(lslots[i]))
+                req.generated = 1
+                rid = req.req_id
+                if req.preserved_tokens:
+                    # Squash survivor: restore the streamed prefix and its
+                    # latency records; re-execution regenerates (and the
+                    # handle ignores) positions the user already has.
+                    self.outputs[rid] = list(req.preserved_tokens)
+                    self._tbts[rid] = list(req.preserved_tbts)
+                    if req.last_stream_time is not None:
+                        self._last_tok[rid] = req.last_stream_time
+                else:
+                    self.outputs[rid] = []
+                    self._tbts[rid] = []
+                    req.first_token_time = now
+                self._record_token(req, 0, first, now)
         if self.paged:
             self.kv_pages = (kp, vp)
         else:
@@ -1309,6 +1367,27 @@ class ChameleonEngine:
         return (np.arange(req.input_len) % self.cfg.vocab_size) \
             .astype(np.int32)
 
+    def _prefill_span_for(self, reqs: list[Request], B: int, S: int):
+        """The ``engine.prefill`` span of one prefill call, from its
+        dispatch until its first tokens are on the host; each request
+        it serves first is remembered as its ``request.prefill``'s
+        parent."""
+        if not self.spans.on:
+            return NULL_SPAN
+        sp = self.spans.span("engine.prefill", B=B, S=S,
+                             req_ids=[r.req_id for r in reqs])
+        for r in reqs:
+            self._prefill_span.setdefault(r.req_id, sp.id)
+        return sp
+
+    def _first_tokens(self, logits, reqs: list[Request], B: int
+                      ) -> np.ndarray:
+        """The first token of each prefilled row, on the host."""
+        if self._all_greedy(reqs):
+            return np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        return np.asarray(self._sample_jit(
+            logits, *self._sampling_arrays(reqs, B, first=True)))
+
     def _sig_of(self, req: Request) -> int:
         """KV signature a cached page is keyed by (see EngineConfig
         .prefix_mode): the adapter in exact mode, one shared tree in
@@ -1326,61 +1405,67 @@ class ChameleonEngine:
         free = self._free_slots()
         ps = self.pool.page_size
         placed, slots, starts, prompts = [], [], [], []
-        for req in reqs:
-            slot = free[len(placed)]
-            toksr = self._prompt_tokens(req)
-            L = req.input_len
-            sig = self._sig_of(req)
-            # Cap the match at L-1: the last prompt position always
-            # prefills, so first-token logits are computed fresh.
-            pages, m, ppage, plen = self.prefix.match(sig, toksr, L - 1)
-            # Reference everything we plan to read *before* allocating
-            # (allocation pressure may evict refcount-1 tree pages —
-            # ours must not be candidates).
-            self.pool.share_pages(pages)
-            if ppage is not None:
-                self.pool.share_pages([ppage])
-            self.slot_req[slot] = req
-            self.slot_pages[slot] = list(pages)
-            self.slot_shared[slot] = list(pages)
-            if pages:
-                self.page_table[slot, :len(pages)] = pages
-                self._page_table_dirty = True
-            n_priv = self.pool.pages_for(L) - len(pages)
-            if not self._grow_slot(slot, n_priv, now):
-                # Bounce: undo the mapping and requeue (squash path).
-                self.free_pages.extend(self.pool.release_shared(pages))
+        # Mapping each prompt onto pages (shared, forked, allocated) is
+        # this path's page placement: the prefill writes the K/V itself.
+        with self.spans.span("engine.kv_place") as kv:
+            for req in reqs:
+                slot = free[len(placed)]
+                toksr = self._prompt_tokens(req)
+                L = req.input_len
+                sig = self._sig_of(req)
+                # Cap the match at L-1: the last prompt position always
+                # prefills, so first-token logits are computed fresh.
+                pages, m, ppage, plen = self.prefix.match(sig, toksr, L - 1)
+                # Reference everything we plan to read *before* allocating
+                # (allocation pressure may evict refcount-1 tree pages —
+                # ours must not be candidates).
+                self.pool.share_pages(pages)
                 if ppage is not None:
-                    self.free_pages.extend(
-                        self.pool.release_shared([ppage]))
-                self.slot_pages[slot] = []
-                self.slot_shared[slot] = []
-                self.page_table[slot, :] = 0
-                self.slot_req[slot] = None
-                self.n_preempted += 1
-                self.sched.on_squash(req, now)
-                continue
-            start = m
-            if ppage is not None:
-                # Divergence mid-page: copy the agreeing head of the
-                # cached page into the request's first private page
-                # (which the suffix prefill then extends in place).
-                dst = self.slot_pages[slot][len(pages)]
-                kp, vp = self.kv_pages
-                kp = kp.at[:, dst, :plen].set(kp[:, ppage, :plen])
-                vp = vp.at[:, dst, :plen].set(vp[:, ppage, :plen])
-                self.kv_pages = (kp, vp)
-                self.free_pages.extend(self.pool.release_shared([ppage]))
-                self.n_cow_forks += 1
-                start = m + plen
-            self.prefix_lookup_tokens += L
-            self.prefix_hit_tokens += start
-            if start:
-                self.n_prefix_hits += 1
-            placed.append(req)
-            slots.append(slot)
-            starts.append(start)
-            prompts.append(toksr)
+                    self.pool.share_pages([ppage])
+                self.slot_req[slot] = req
+                self.slot_pages[slot] = list(pages)
+                self.slot_shared[slot] = list(pages)
+                if pages:
+                    self.page_table[slot, :len(pages)] = pages
+                    self._page_table_dirty = True
+                n_priv = self.pool.pages_for(L) - len(pages)
+                if not self._grow_slot(slot, n_priv, now):
+                    # Bounce: undo the mapping and requeue (squash path).
+                    self.free_pages.extend(self.pool.release_shared(pages))
+                    if ppage is not None:
+                        self.free_pages.extend(
+                            self.pool.release_shared([ppage]))
+                    self.slot_pages[slot] = []
+                    self.slot_shared[slot] = []
+                    self.page_table[slot, :] = 0
+                    self.slot_req[slot] = None
+                    self.n_preempted += 1
+                    self.sched.on_squash(req, now)
+                    continue
+                start = m
+                if ppage is not None:
+                    # Divergence mid-page: copy the agreeing head of the
+                    # cached page into the request's first private page
+                    # (which the suffix prefill then extends in place).
+                    with self.spans.span("engine.cow_fork"):
+                        dst = self.slot_pages[slot][len(pages)]
+                        kp, vp = self.kv_pages
+                        kp = kp.at[:, dst, :plen].set(kp[:, ppage, :plen])
+                        vp = vp.at[:, dst, :plen].set(vp[:, ppage, :plen])
+                        self.kv_pages = (kp, vp)
+                    self.free_pages.extend(self.pool.release_shared([ppage]))
+                    self.n_cow_forks += 1
+                    start = m + plen
+                self.prefix_lookup_tokens += L
+                self.prefix_hit_tokens += start
+                if start:
+                    self.n_prefix_hits += 1
+                placed.append(req)
+                slots.append(slot)
+                starts.append(start)
+                prompts.append(toksr)
+            if kv:
+                kv.set(pages=sum(len(self.slot_pages[s]) for s in slots))
         if not placed:
             return
         S = 1 << max(3, (max(r.input_len - s for r, s in
@@ -1402,18 +1487,14 @@ class ChameleonEngine:
             # The COW fork above host-updates the (donated) pool —
             # re-commit so the explicit in_shardings hold exactly.
             self.kv_pages = self._commit(self.kv_pages, self._kv_sh)
-        with self._act_scope():
-            logits, self.kv_pages = self._get_prefill_paged_jit(B, S)(
-                self.params, self._prefill_lora(), jnp.asarray(toks),
-                self.kv_pages, jnp.asarray(row_table),
-                jnp.asarray(start_arr), jnp.asarray(seq_len),
-                jnp.asarray(lslots), S)
-        if self._all_greedy(placed):
-            first_toks = np.asarray(
-                jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        else:
-            first_toks = np.asarray(self._sample_jit(
-                logits, *self._sampling_arrays(placed, B, first=True)))
+        with self._prefill_span_for(placed, B, S):
+            with self._act_scope():
+                logits, self.kv_pages = self._get_prefill_paged_jit(B, S)(
+                    self.params, self._prefill_lora(), jnp.asarray(toks),
+                    self.kv_pages, jnp.asarray(row_table),
+                    jnp.asarray(start_arr), jnp.asarray(seq_len),
+                    jnp.asarray(lslots), S)
+            first_toks = self._first_tokens(logits, placed, B)
         now = self.now()
         for i, req in enumerate(placed):
             slot = slots[i]
@@ -1519,19 +1600,22 @@ class ChameleonEngine:
             toks[0, :n] = st["prompt"][done:done + n]
             row_table = st["table"][None, :]
             lslot = self.slot_of[req.adapter_id]
-            with self._act_scope():
-                logits, self.kv_pages = self._get_prefill_paged_jit(1, S)(
-                    self.params, self._prefill_lora(), jnp.asarray(toks),
-                    self.kv_pages, jnp.asarray(row_table),
-                    jnp.asarray([done], np.int32),
-                    jnp.asarray([n], np.int32),
-                    jnp.asarray([lslot], np.int32), S)
-            st["done"] = done + n
-            if st["done"] >= L:
-                del self._chunked[slot]
-                self.page_table[slot] = st["table"]
-                self._page_table_dirty = True
-                self._activate_chunked(slot, req, logits, lslot)
+            with self._prefill_span_for([req], 1, S):
+                with self._act_scope():
+                    logits, self.kv_pages = self._get_prefill_paged_jit(
+                        1, S)(
+                        self.params, self._prefill_lora(),
+                        jnp.asarray(toks), self.kv_pages,
+                        jnp.asarray(row_table),
+                        jnp.asarray([done], np.int32),
+                        jnp.asarray([n], np.int32),
+                        jnp.asarray([lslot], np.int32), S)
+                st["done"] = done + n
+                if st["done"] >= L:
+                    del self._chunked[slot]
+                    self.page_table[slot] = st["table"]
+                    self._page_table_dirty = True
+                    self._activate_chunked(slot, req, logits, lslot)
 
     def _activate_chunked(self, slot: int, req: Request, logits,
                           lslot: int) -> None:
@@ -1804,6 +1888,7 @@ class ChameleonEngine:
         tbts = self._tbts.pop(req.req_id, [])
         req.preserved_tbts = tbts    # handle.result() reads these
         self._last_tok.pop(req.req_id, None)
+        self._record_request_spans(req)
         if state is RequestState.CANCELLED:
             self.n_cancelled += 1
             return
@@ -1914,17 +1999,41 @@ class ChameleonEngine:
         if self._pending_loads and self._clock is None:
             t_next = min(t for _, _, t in self._pending_loads.values())
             wait = t_next - self.now()
-            time.sleep(min(max(wait, 1e-4), 0.05))
+            with self.spans.span("engine.idle_wait"):
+                time.sleep(min(max(wait, 1e-4), 0.05))
 
     def step(self) -> None:
         """One engine iteration: retire finished loads -> enforce
         deadlines/cancellations -> admit -> prefetch -> batched prefill
         -> decode. With ``fused_hotloop`` the decode half is the
         device-resident fused loop (one dispatch per K-token horizon);
-        otherwise the seed two-dispatch loop runs."""
-        if self.fused:
-            return self._step_fused()
-        return self._step_seed()
+        otherwise the seed two-dispatch loop runs. While a profiler
+        capture runs, the step and its phases are ``engine.*`` spans
+        (``serving/spans.py``)."""
+        loop = self._step_fused if self.fused else self._step_seed
+        if not self.spans.on:
+            return loop()
+        with self.spans.span("engine.step") as sp:
+            sp.set(rows=int(self.active.sum()),
+                   queued=self.sched.pending_count())
+            self._horizon_k = 0
+            loop()
+            sp.set(K=self._horizon_k)
+
+    def _schedule(self) -> list[Request]:
+        """Lifecycle sweep, admission and prefetch at a step boundary;
+        returns the requests admitted."""
+        now = self.now()
+        with self.spans.span("engine.schedule") as sp:
+            deferred = getattr(self.sched, "n_deferred", 0)
+            self._sweep_lifecycle(now)
+            running = [r for r in self.slot_req if r is not None]
+            admitted = self.sched.schedule(now, running)
+            self._run_prefetchers(now)
+            if sp:
+                sp.set(admitted=len(admitted), deferred=getattr(
+                    self.sched, "n_deferred", 0) - deferred)
+        return admitted
 
     def _step_seed(self) -> None:
         """The seed decode loop: one decode dispatch, logits back to a
@@ -1933,47 +2042,43 @@ class ChameleonEngine:
         sync before bookkeeping. Kept verbatim (plus the dispatch
         meter) as the ``decode_hotloop.py`` A/B baseline and the
         fallback for model families without fused decode support."""
-        self._poll_loads()
-        now = self.now()
-        self._sweep_lifecycle(now)
-        running = [r for r in self.slot_req if r is not None]
-        admitted = self.sched.schedule(now, running)
-        self._run_prefetchers(now)
-        self._place_batch(admitted)
+        with self.spans.span("engine.poll_loads"):
+            self._poll_loads()
+        self._place_batch(self._schedule())
         self._advance_chunked()
         if self.paged:
             self._ensure_decode_pages()
         if not self.active.any():
             self._idle_wait()
             return
-        self.batch_occupancy.append(int(self.active.sum()))
-        self._commit_batch_state()
-        DISPATCH_METER.tick()
-        if self._collective:
-            COLLECTIVE_METER.tick()
-        with self._act_scope():
-            if self.paged:
-                logits, self.kv_pages = self._decode_paged_jit(
-                    self.params, self.lora, self.tokens, self.kv_pages,
-                    jnp.asarray(self.page_table), self.cache_len,
-                    self.adapter_slot)
+        rows = int(self.active.sum())
+        self.batch_occupancy.append(rows)
+        self._horizon_k = 1
+        with self.spans.span("engine.decode", K=1, rows=rows, spec=False):
+            self._commit_batch_state()
+            DISPATCH_METER.tick()
+            with self._act_scope():
+                if self.paged:
+                    logits, self.kv_pages = self._decode_paged_jit(
+                        self.params, self.lora, self.tokens, self.kv_pages,
+                        jnp.asarray(self.page_table), self.cache_len,
+                        self.adapter_slot)
+                else:
+                    logits, self.kv = self._decode_jit(
+                        self.params, self.lora, self.tokens, self.kv,
+                        self.cache_len, self.adapter_slot)
+            DISPATCH_METER.tick()
+            if self._all_greedy(self.slot_req):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             else:
-                logits, self.kv = self._decode_jit(
-                    self.params, self.lora, self.tokens, self.kv,
-                    self.cache_len, self.adapter_slot)
-        DISPATCH_METER.tick()
-        if self._all_greedy(self.slot_req):
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            nxt = self._sample_jit(
-                logits, *self._sampling_arrays(self.slot_req,
-                                               self.ecfg.max_slots))
-        self.tokens = nxt[:, None]
-        self.cache_len = self.cache_len + jnp.asarray(self.active,
-                                                      jnp.int32)
+                nxt = self._sample_jit(
+                    logits, *self._sampling_arrays(self.slot_req,
+                                                   self.ecfg.max_slots))
+            self.tokens = nxt[:, None]
+            self.cache_len = self.cache_len + jnp.asarray(self.active,
+                                                          jnp.int32)
         now = self.now()
-        with DISPATCH_METER.sync(), COLLECTIVE_METER.sync() \
-                if self._collective else contextlib.nullcontext():
+        with DISPATCH_METER.sync(), self.spans.span("engine.readback"):
             nxt_host = np.asarray(nxt)
         to_finish, to_squash = [], []
         for slot in np.where(self.active)[0]:
@@ -2228,42 +2333,47 @@ class ChameleonEngine:
                     self._shrink_spec_pages()
                     return False
                 kk = 1 << (kk.bit_length() - 1)
-        self._refresh_device_state()
-        self._draft_sync()
-        d = self._dev
-        self._commit_batch_state()
-        DISPATCH_METER.tick()
-        DISPATCH_METER.tick_draft(n_rounds * (kk + 1))
-        DISPATCH_METER.tick_verify(n_rounds)
-        self.n_spec_dispatches += 1
-        self.n_spec_draft_dispatches += n_rounds * (kk + 1)
-        self.n_spec_verify_dispatches += n_rounds
-        with self._act_scope():
-            if self.paged:
-                if self._page_table_dirty or self._page_table_dev is None:
-                    self._page_table_dev = jnp.asarray(self.page_table)
-                    self._page_table_dirty = False
-                carry, toks, emits, accs = self._spec_paged_jit(
-                    self.params, self.lora, self.tokens, self.kv_pages,
-                    self._page_table_dev, self.draft_kv, self.cache_len,
-                    d["active"], d["positions"], self.adapter_slot,
-                    d["budget"], d["stop"], d["temp"], d["topk"],
-                    d["topp"], d["seeds"], spec_k=kk, n_rounds=n_rounds,
-                    all_greedy=d["all_greedy"])
-                (self.tokens, self.kv_pages, self.draft_kv,
-                 self.cache_len, d["active"], d["positions"]) = carry
-            else:
-                carry, toks, emits, accs = self._spec_jit(
-                    self.params, self.lora, self.tokens, self.kv,
-                    self.draft_kv, self.cache_len, d["active"],
-                    d["positions"], self.adapter_slot, d["budget"],
-                    d["stop"], d["temp"], d["topk"], d["topp"],
-                    d["seeds"], spec_k=kk, n_rounds=n_rounds,
-                    all_greedy=d["all_greedy"])
-                (self.tokens, self.kv, self.draft_kv, self.cache_len,
-                 d["active"], d["positions"]) = carry
-        self._inflight = (toks, emits, n_rounds * (kk + 1),
-                          (accs, kk, n_rounds))
+        self._horizon_k = n_rounds * (kk + 1)
+        with self.spans.span("engine.decode", K=n_rounds * (kk + 1),
+                             spec=True) as sp:
+            if sp:
+                sp.set(rows=int(self.active.sum()))
+            self._refresh_device_state()
+            self._draft_sync()
+            d = self._dev
+            self._commit_batch_state()
+            DISPATCH_METER.tick()
+            DISPATCH_METER.tick_draft(n_rounds * (kk + 1))
+            DISPATCH_METER.tick_verify(n_rounds)
+            self.n_spec_dispatches += 1
+            self.n_spec_draft_dispatches += n_rounds * (kk + 1)
+            self.n_spec_verify_dispatches += n_rounds
+            with self._act_scope():
+                if self.paged:
+                    if self._page_table_dirty or self._page_table_dev is None:
+                        self._page_table_dev = jnp.asarray(self.page_table)
+                        self._page_table_dirty = False
+                    carry, toks, emits, accs = self._spec_paged_jit(
+                        self.params, self.lora, self.tokens, self.kv_pages,
+                        self._page_table_dev, self.draft_kv, self.cache_len,
+                        d["active"], d["positions"], self.adapter_slot,
+                        d["budget"], d["stop"], d["temp"], d["topk"],
+                        d["topp"], d["seeds"], spec_k=kk, n_rounds=n_rounds,
+                        all_greedy=d["all_greedy"])
+                    (self.tokens, self.kv_pages, self.draft_kv,
+                     self.cache_len, d["active"], d["positions"]) = carry
+                else:
+                    carry, toks, emits, accs = self._spec_jit(
+                        self.params, self.lora, self.tokens, self.kv,
+                        self.draft_kv, self.cache_len, d["active"],
+                        d["positions"], self.adapter_slot, d["budget"],
+                        d["stop"], d["temp"], d["topk"], d["topp"],
+                        d["seeds"], spec_k=kk, n_rounds=n_rounds,
+                        all_greedy=d["all_greedy"])
+                    (self.tokens, self.kv, self.draft_kv, self.cache_len,
+                     d["active"], d["positions"]) = carry
+            self._inflight = (toks, emits, n_rounds * (kk + 1),
+                              (accs, kk, n_rounds))
         self._drain_inflight()
         # The draft cache advanced in lockstep with the target for
         # every surviving slot (garbage entries past the accepted
@@ -2292,40 +2402,42 @@ class ChameleonEngine:
         the carried active mask; a host-side squash leaves its row
         decoding masked garbage until the next synced placement rebuild.
         """
-        if refresh:
-            self._refresh_device_state()
-        d = self._dev
-        self._commit_batch_state()
-        DISPATCH_METER.tick()
-        if self._collective:
-            COLLECTIVE_METER.tick()
-        with self._act_scope():
-            if self.paged:
-                if self._page_table_dirty or self._page_table_dev is None:
-                    self._page_table_dev = jnp.asarray(self.page_table)
-                    if self.plan is not None:
-                        self._page_table_dev = jax.device_put(
-                            self._page_table_dev,
-                            self._batch_sh(2))
-                    self._page_table_dirty = False
-                carry, toks, emits = self._fused_paged_jit(
-                    self.params, self.lora, self.tokens, self.kv_pages,
-                    self._page_table_dev, self.cache_len, d["active"],
-                    d["positions"], self.adapter_slot, d["budget"],
-                    d["stop"], d["temp"], d["topk"], d["topp"], d["seeds"],
-                    K=K, all_greedy=d["all_greedy"])
-                (self.tokens, self.kv_pages, self.cache_len,
-                 d["active"], d["positions"]) = carry
-            else:
-                carry, toks, emits = self._fused_jit(
-                    self.params, self.lora, self.tokens, self.kv,
-                    self.cache_len, d["active"], d["positions"],
-                    self.adapter_slot, d["budget"], d["stop"], d["temp"],
-                    d["topk"], d["topp"], d["seeds"],
-                    K=K, all_greedy=d["all_greedy"])
-                (self.tokens, self.kv, self.cache_len,
-                 d["active"], d["positions"]) = carry
-        self._inflight = (toks, emits, K)
+        self._horizon_k = K
+        with self.spans.span("engine.decode", K=K, spec=False) as sp:
+            if sp:
+                sp.set(rows=int(self.active.sum()))
+            if refresh:
+                self._refresh_device_state()
+            d = self._dev
+            self._commit_batch_state()
+            DISPATCH_METER.tick()
+            with self._act_scope():
+                if self.paged:
+                    if self._page_table_dirty or self._page_table_dev is None:
+                        self._page_table_dev = jnp.asarray(self.page_table)
+                        if self.plan is not None:
+                            self._page_table_dev = jax.device_put(
+                                self._page_table_dev,
+                                self._batch_sh(2))
+                        self._page_table_dirty = False
+                    carry, toks, emits = self._fused_paged_jit(
+                        self.params, self.lora, self.tokens, self.kv_pages,
+                        self._page_table_dev, self.cache_len, d["active"],
+                        d["positions"], self.adapter_slot, d["budget"],
+                        d["stop"], d["temp"], d["topk"], d["topp"], d["seeds"],
+                        K=K, all_greedy=d["all_greedy"])
+                    (self.tokens, self.kv_pages, self.cache_len,
+                     d["active"], d["positions"]) = carry
+                else:
+                    carry, toks, emits = self._fused_jit(
+                        self.params, self.lora, self.tokens, self.kv,
+                        self.cache_len, d["active"], d["positions"],
+                        self.adapter_slot, d["budget"], d["stop"], d["temp"],
+                        d["topk"], d["topp"], d["seeds"],
+                        K=K, all_greedy=d["all_greedy"])
+                    (self.tokens, self.kv, self.cache_len,
+                     d["active"], d["positions"]) = carry
+            self._inflight = (toks, emits, K)
 
     def _drain_inflight(self) -> None:
         """Sync the in-flight horizon's token block and replay the
@@ -2339,8 +2451,7 @@ class ChameleonEngine:
         toks, emits, _K = self._inflight[:3]
         spec_meta = self._inflight[3] if len(self._inflight) > 3 else None
         self._inflight = None
-        with DISPATCH_METER.sync(), COLLECTIVE_METER.sync() \
-                if self._collective else contextlib.nullcontext():
+        with DISPATCH_METER.sync(), self.spans.span("engine.readback"):
             toks_h = np.asarray(toks)
             emits_h = np.asarray(emits)
             accs_h = (np.asarray(spec_meta[0])
@@ -2445,7 +2556,8 @@ class ChameleonEngine:
         host work (admissions, lifecycle, loads) first syncs the
         in-flight horizon, then runs the same admit/place path as the
         seed loop."""
-        self._poll_loads()
+        with self.spans.span("engine.poll_loads"):
+            self._poll_loads()
         if self._inflight is not None:
             k_next = self._plan_pipelined_horizon()
             if k_next is not None:
@@ -2456,12 +2568,7 @@ class ChameleonEngine:
                 self._inflight = nxt
                 return
             self._drain_inflight()
-        now = self.now()
-        self._sweep_lifecycle(now)
-        running = [r for r in self.slot_req if r is not None]
-        admitted = self.sched.schedule(now, running)
-        self._run_prefetchers(now)
-        self._place_batch(admitted)
+        self._place_batch(self._schedule())
         self._advance_chunked()
         if self.paged:
             self._ensure_decode_pages(self._host_lens())
@@ -2514,6 +2621,8 @@ class ChameleonEngine:
         self._last_tok = {}
         self.handles = {}
         self.batch_occupancy = []
+        self.spans.clear()
+        self._prefill_span = {}
         self.n_preempted = 0
         self.n_cancelled = 0
         self.n_expired = 0
@@ -2615,9 +2724,9 @@ class ChameleonEngine:
 
     def shard_stats(self) -> dict:
         """Per-device data-plane gauges (empty dict off-mesh): physical
-        page occupancy per data shard, resident LoRA-arena bytes per
-        device, and the collective time fraction from the
-        COLLECTIVE_METER probe."""
+        page occupancy per data shard and resident LoRA-arena bytes per
+        device. Collective time is in a profiler capture's device
+        trace (docs/OPERATIONS.md §2.5)."""
         if self.mesh is None:
             return {}
         out = {
@@ -2642,9 +2751,6 @@ class ChameleonEngine:
             arena += a.addressable_shards[0].data.nbytes
             arena += b.addressable_shards[0].data.nbytes
         out["per_shard_lora_slot_bytes"] = arena
-        if self._collective:
-            out["collective_frac"] = round(COLLECTIVE_METER.frac(), 4)
-            out["collective_dispatches"] = COLLECTIVE_METER.dispatches
         return out
 
     def stats(self) -> dict:

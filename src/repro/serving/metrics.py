@@ -59,8 +59,6 @@ GAUGES: dict = {
     "per_shard_pages_used": ("pages", "KV pages used per data shard"),
     "per_shard_pages_total": ("pages", "KV pages per data shard"),
     "per_shard_lora_slot_bytes": ("bytes", "LoRA arena bytes on one device"),
-    "collective_frac": ("ratio", "wall-time fraction spent in collectives"),
-    "collective_dispatches": ("count", "jit dispatches containing collectives"),
     # Disaggregated prefill/decode (serving/disagg.py). The first five
     # are per-engine (chunked prefill + KV handoff endpoints); the rest
     # are cluster-level, injected by DisaggCluster.metrics().
@@ -219,10 +217,9 @@ def merge_metrics(per_node: list[RunMetrics],
     # Non-additive gauges: report the worst node instead of a sum.
     # kv_page_util / batch_occupancy_mean are fractions of per-node
     # capacity; kv_pages_used/total and preempted counts stay additive.
-    # collective_frac (sharded engines) is a wall-time fraction.
     ratio_gauges = ("link_busy_frac", "pressure", "kv_page_util",
                     "batch_occupancy_mean", "prefix_hit_rate",
-                    "collective_frac", "gw_reject_rate",
+                    "gw_reject_rate",
                     "gw_degrade_rate", "gw_queue_wait_est_s",
                     "spec_accept_rate", "spec_k_eff")
     merged = RunMetrics(

@@ -168,9 +168,6 @@ class TestShardTelemetry:
         assert len(s["per_shard_pages_used"]) == d
         assert s["per_shard_pages_total"] * d == eng.n_pages
         assert s["per_shard_lora_slot_bytes"] > 0
-        if d * m > 1:
-            assert s["collective_dispatches"] > 0
-            assert 0.0 <= s["collective_frac"] <= 1.0
         # Gauges flow into the metrics surface for cluster merging.
         assert eng.metrics().sched_stats["n_devices"] == d * m
 

@@ -14,6 +14,7 @@ import: only one process may hold the TPU library, and every test
 worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -65,33 +66,20 @@ PROJ = {"q": (CFG.d_model, CFG.q_dim), "k": (CFG.d_model, CFG.kv_dim),
         "o": (CFG.q_dim, CFG.d_model)}
 
 
-@pytest.mark.parametrize("proj", sorted(PROJ))
-def test_bgmv_decode_compiles(one_chip, proj):
+def _lora_text(one_chip, rows, S, proj):
+    """The LoRA delta for ``rows`` runs of ``S`` tokens (S == 1 is the
+    bgmv decode path, S > 1 the sgmv prefill path), compiled."""
     din, dout = PROJ[proj]
-    args = (_spec((ROWS, 1, din), jnp.float32, one_chip),
+    args = (_spec((rows, S, din), jnp.float32, one_chip),
             _spec((N_SLOTS, din, R_MAX), jnp.bfloat16, one_chip),
             _spec((N_SLOTS, R_MAX, dout), jnp.bfloat16, one_chip),
-            _spec((ROWS,), jnp.int32, one_chip))
-    txt = _compiled_text(
+            _spec((rows,), jnp.int32, one_chip))
+    return _compiled_text(
         lambda x, a, b, i: ops.lora_delta_kernel(x, a, b, i,
                                                  interpret=False), *args)
-    assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("B,S", [(1, 512), (4, 128)])
-def test_sgmv_prefill_compiles(one_chip, B, S):
-    din, dout = PROJ["q"]
-    args = (_spec((B, S, din), jnp.float32, one_chip),
-            _spec((N_SLOTS, din, R_MAX), jnp.bfloat16, one_chip),
-            _spec((N_SLOTS, R_MAX, dout), jnp.bfloat16, one_chip),
-            _spec((B,), jnp.int32, one_chip))
-    txt = _compiled_text(
-        lambda x, a, b, i: ops.lora_delta_kernel(x, a, b, i,
-                                                 interpret=False), *args)
-    assert "tpu_custom_call" in txt
-
-
-def test_paged_attention_compiles(one_chip):
+def _paged_attention_text(one_chip):
     Kh, G, dh = CFG.n_kv_heads, CFG.n_heads // CFG.n_kv_heads, CFG.head_dim
     pool = (N_PAGES, PAGE, Kh, dh)
     args = (_spec((ROWS, Kh, G, dh), jnp.float32, one_chip),
@@ -99,7 +87,32 @@ def test_paged_attention_compiles(one_chip):
             _spec(pool, jnp.float32, one_chip),
             _spec((ROWS, MAX_LEN // PAGE), jnp.int32, one_chip),
             _spec((ROWS,), jnp.int32, one_chip))
-    txt = _compiled_text(
+    return _compiled_text(
         lambda q, k, v, pt, ln: ops.paged_attention(
             q, k, v, pt, ln, prefer_kernel=True, interpret=False), *args)
-    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("proj", sorted(PROJ))
+def test_bgmv_decode_compiles(one_chip, proj):
+    assert "tpu_custom_call" in _lora_text(one_chip, ROWS, 1, proj)
+
+
+@pytest.mark.parametrize("B,S", [(1, 512), (4, 128)])
+def test_sgmv_prefill_compiles(one_chip, B, S):
+    assert "tpu_custom_call" in _lora_text(one_chip, B, S, "q")
+
+
+def test_paged_attention_compiles(one_chip):
+    assert "tpu_custom_call" in _paged_attention_text(one_chip)
+
+
+@pytest.mark.parametrize("kernel", ["bgmv", "sgmv", "paged_attention"])
+def test_kernel_names_in_compile(one_chip, kernel):
+    """Each kernel's ``pallas_call`` names its op, as the benchmark's
+    trace readers key on it (``%bgmv``, ``%sgmv``, ``%paged_attention``)."""
+    txt = (_paged_attention_text(one_chip) if kernel == "paged_attention"
+           else _lora_text(one_chip, 4, 1 if kernel == "bgmv" else 128,
+                           "q"))
+    op = re.compile(rf"%{kernel}(\.\d+)? = \S+ custom-call\(.*"
+                    r'custom_call_target="tpu_custom_call"')
+    assert any(op.search(line) for line in txt.splitlines()), kernel
