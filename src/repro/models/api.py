@@ -16,6 +16,7 @@ from . import encdec, hybrid, lm, ssm_lm
 from .base import Family, ModelConfig
 from .lm import init_params  # shared: param_shapes covers every family
 from .lm import sample_tokens  # family-agnostic: operates on logits
+from .lm import write_prompt_pages  # the paged pool's layout
 
 
 def _mod(cfg: ModelConfig):

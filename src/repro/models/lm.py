@@ -395,6 +395,42 @@ def make_paged_kv(cfg: ModelConfig, n_pages: int, page_size: int,
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
+def write_prompt_pages(kv_pages, k_new: jax.Array, v_new: jax.Array,
+                       page_rows: jax.Array):
+    """Write a prefill's K/V into whole pages of the pool, in one program.
+
+    kv_pages: (k_pages, v_pages) each (L, n_pages, page, Kh, Dh);
+    k_new/v_new: (L, B, S, Kh, Dh) from ``prefill``; page_rows: (B, P)
+    with P = ceil(S / page), row i's j-th page at [i, j]. Entries past a
+    row's prompt pages, and every entry of a pad row, are the trash page
+    0. A row's positions past its prompt length land in its last page as
+    K/V of padding tokens: attention masks at ``cache_len`` and decode
+    overwrites them before they are read.
+
+    Donate ``kv_pages``: each page is one in-place dynamic-update-slice
+    inside a loop. A single scatter of all pages would have XLA on TPU
+    first copy the (L, B*P, ...) updates into page-major order, a second
+    copy of the prefill's K/V in HBM.
+    """
+    k_pages, v_pages = kv_pages
+    page = k_pages.shape[2]
+    L, B, S = k_new.shape[:3]
+    P = page_rows.shape[1]
+    if P * page != S:               # an S = 8 bucket under 16-token pages
+        pad = ((0, 0), (0, 0), (0, P * page - S), (0, 0), (0, 0))
+        k_new, v_new = jnp.pad(k_new, pad), jnp.pad(v_new, pad)
+    size = (L, 1, page) + k_new.shape[3:]
+
+    def body(j, kv):
+        i, q = j // P, j % P
+        src, dst = (0, i, q * page, 0, 0), (0, page_rows[i, q], 0, 0, 0)
+        return tuple(jax.lax.dynamic_update_slice(
+            pool, jax.lax.dynamic_slice(new, src, size), dst)
+            for pool, new in zip(kv, (k_new, v_new)))
+
+    return jax.lax.fori_loop(0, B * P, body, (k_pages, v_pages))
+
+
 # ------------------------------------------------------------- sampling
 def sample_tokens(logits: jax.Array, temperature: jax.Array,
                   top_k: jax.Array, top_p: jax.Array, seeds: jax.Array,
@@ -662,7 +698,7 @@ def prefill_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     attention over it — the cached prefix participates as keys without
     being recomputed. Returns (last-valid-position logits (B, V),
     kv_pages'). On a miss row (start == 0) this computes exactly what
-    ``prefill`` + the host page scatter produced, so one code path
+    ``prefill`` + ``write_prompt_pages`` produce, so one code path
     serves hits and misses.
     """
     B, S = tokens.shape

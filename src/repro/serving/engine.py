@@ -554,6 +554,11 @@ class ChameleonEngine:
         self._prefill_paged_jit = jax.jit(self._prefill_paged_fn,
                                           static_argnames=("S",),
                                           donate_argnums=(3,))
+        # A fresh prefill's K/V into its pages (paged, prefix off): one
+        # program writes every page into the donated pool in place; one
+        # compile per (B, S) prefill bucket.
+        self._place_pages_jit = jax.jit(api.write_prompt_pages,
+                                        donate_argnums=(0,))
         self._sample_jit = jax.jit(api.sample_tokens)
         if self.spec:
             # Speculative round: tokens, target KV, draft KV, cache_len,
@@ -796,6 +801,26 @@ class ChameleonEngine:
                               bB, bB, bB),
                 out_shardings=(pl.logits((B, cfg.vocab_size)),
                                self._kv_sh))
+            self._sharded_prefill_cache[key] = jitf
+        return jitf
+
+    def _get_place_pages_jit(self, B: int, S: int):
+        """Sharded page write for one (B, S) prefill bucket: the pool
+        keeps its pages-over-"data" sharding (donated), the prefill's
+        K/V comes in as the dense prefill jit left it."""
+        if self.plan is None:
+            return self._place_pages_jit
+        key = ("place", B, S)
+        jitf = self._sharded_prefill_cache.get(key)
+        if jitf is None:
+            pl, cfg = self.plan, self.cfg
+            kv = pl.kv_dense((cfg.n_layers, B, S, cfg.n_kv_heads,
+                              cfg.head_dim))
+            P = -(-S // self.pool.page_size)
+            jitf = jax.jit(
+                api.write_prompt_pages, donate_argnums=(0,),
+                in_shardings=(self._kv_sh, kv, kv, pl.batch((B, P))),
+                out_shardings=self._kv_sh)
             self._sharded_prefill_cache[key] = jitf
         return jitf
 
@@ -1260,7 +1285,7 @@ class ChameleonEngine:
                 with self.spans.span("engine.kv_place") as sp:
                     if sp:
                         sp.set(pages=sum(self.pool.pages_for(r.input_len)
-                                         for r in big))
+                                         for r in big), programs=0)
                     self._start_chunked(big)
             if not reqs:
                 return
@@ -1301,33 +1326,38 @@ class ChameleonEngine:
                     self.params, self._prefill_lora(), jnp.asarray(toks),
                     jnp.asarray(lslots), jnp.asarray(last_pos), S)
             first_toks = self._first_tokens(logits, reqs, B)
-        if self.paged:
-            kp, vp = self.kv_pages
-        else:
-            k, v = self.kv
         now = self.now()
-        ps = self.pool.page_size
-        # The eager page loop: each prompt's K/V into its pages, and its
-        # row seated with the first token.
+        # Each prompt's K/V into its slot, and its row seated with the
+        # first token.
         with self.spans.span("engine.kv_place") as sp:
+            if self.paged:
+                # One program writes whole pages; a pad row and the
+                # pages past a prompt point at the trash page 0.
+                rows = np.zeros((B, -(-S // self.pool.page_size)),
+                                np.int32)
+                for i in range(len(reqs)):
+                    pages = self.slot_pages[free[i]]
+                    rows[i, :len(pages)] = pages
+                self.kv_pages = self._get_place_pages_jit(B, S)(
+                    self.kv_pages, k_new, v_new, jnp.asarray(rows))
+                programs = 1
+            else:
+                k, v = self.kv
+                for i, req in enumerate(reqs):
+                    L = req.input_len
+                    k = k.at[:, free[i], :L].set(k_new[:, i, :L])
+                    v = v.at[:, free[i], :L].set(v_new[:, i, :L])
+                self.kv = (k, v)
+                programs = 2 * len(reqs)
             if sp:
                 sp.set(pages=sum(self.pool.pages_for(r.input_len)
-                                 for r in reqs) if self.paged else 0)
+                                 for r in reqs) if self.paged else 0,
+                       programs=programs)
             for i, req in enumerate(reqs):
                 slot = free[i]
                 self.active[slot] = True
                 self.slot_req[slot] = req
                 L = req.input_len
-                if self.paged:
-                    pages = self.slot_pages[slot]
-                    for j in range(0, L, ps):
-                        pid = pages[j // ps]
-                        n = min(ps, L - j)
-                        kp = kp.at[:, pid, :n].set(k_new[:, i, j:j + n])
-                        vp = vp.at[:, pid, :n].set(v_new[:, i, j:j + n])
-                else:
-                    k = k.at[:, slot, :L].set(k_new[:, i, :L])
-                    v = v.at[:, slot, :L].set(v_new[:, i, :L])
                 first = int(first_toks[i])
                 self.tokens = self.tokens.at[slot, 0].set(first)
                 self.cache_len = self.cache_len.at[slot].set(L)
@@ -1348,10 +1378,6 @@ class ChameleonEngine:
                     self._tbts[rid] = []
                     req.first_token_time = now
                 self._record_token(req, 0, first, now)
-        if self.paged:
-            self.kv_pages = (kp, vp)
-        else:
-            self.kv = (k, v)
         self.batch_epoch += 1      # admission boundary: device batch
         for i, req in enumerate(reqs):   # state rebuilds next dispatch
             if req.done or self._hit_stop(req):
@@ -1405,6 +1431,7 @@ class ChameleonEngine:
         free = self._free_slots()
         ps = self.pool.page_size
         placed, slots, starts, prompts = [], [], [], []
+        forks = self.n_cow_forks
         # Mapping each prompt onto pages (shared, forked, allocated) is
         # this path's page placement: the prefill writes the K/V itself.
         with self.spans.span("engine.kv_place") as kv:
@@ -1465,7 +1492,8 @@ class ChameleonEngine:
                 starts.append(start)
                 prompts.append(toksr)
             if kv:
-                kv.set(pages=sum(len(self.slot_pages[s]) for s in slots))
+                kv.set(pages=sum(len(self.slot_pages[s]) for s in slots),
+                       programs=2 * (self.n_cow_forks - forks))
         if not placed:
             return
         S = 1 << max(3, (max(r.input_len - s for r, s in

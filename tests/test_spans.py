@@ -57,13 +57,13 @@ def small_model():
     return cfg, params
 
 
-def serve(small_model, traced_dir=None, clock=None, n=6):
+def serve(small_model, traced_dir=None, clock=None, n=6, **ecfg):
     """A tiny engine with more adapters than slots, run dry; the tokens
     each request streamed, and the engine."""
     cfg, params = small_model
     eng = ChameleonEngine(cfg, params, EngineConfig(
-        max_slots=2, max_len=96, n_lora_slots=2, n_adapters=6, seed=0),
-        clock=clock)
+        max_slots=2, max_len=96, n_lora_slots=2, n_adapters=6, seed=0,
+        **ecfg), clock=clock)
     rng = np.random.default_rng(3)
     handles = [eng.submit(Request(
         input_len=int(rng.integers(4, 24)),
@@ -152,6 +152,17 @@ def test_engine_spans_nest_under_the_step(small_model, tmp_path):
                  "engine.decode", "engine.readback"):
         assert mirrored[name] == names[name], name
     assert eng.stats()["completed"] == 6
+
+
+def test_kv_place_dispatches_one_program_with_the_prefix_cache_off(
+        small_model, tmp_path):
+    """Paged placement without the prefix cache writes every placed
+    row's pages in one device program."""
+    _, eng = serve(small_model, tmp_path, prefix_cache=False)
+    places = [s for s in SPANS.spans if s.name == "engine.kv_place"]
+    assert places and eng.stats()["completed"] == 6
+    assert all(s.attrs["programs"] == 1 and s.attrs["pages"] >= 1
+               for s in places), [s.attrs for s in places]
 
 
 def test_request_spans_share_the_request_id(small_model, tmp_path):

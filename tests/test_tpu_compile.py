@@ -9,6 +9,9 @@ refuses (block shapes off the (8, 128) tiling, VMEM over budget), so
 these are the guard for that. The dtypes are the engine's: float32
 activations and KV, bfloat16 adapter-slot arenas.
 
+The engine's prompt-page write is compiled at the benchmark cell's
+shapes as well, to see that it updates the donated pool in place.
+
 The topology is described inside a module-scoped fixture, never at
 import: only one process may hold the TPU library, and every test
 worker imports this file.
@@ -18,11 +21,13 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
+from repro.models import api
 
 CFG = get_config("internlm2-1.8b")
 R_MAX = 32
@@ -116,3 +121,25 @@ def test_kernel_names_in_compile(one_chip, kernel):
     op = re.compile(rf"%{kernel}(\.\d+)? = \S+ custom-call\(.*"
                     r'custom_call_target="tpu_custom_call"')
     assert any(op.search(line) for line in txt.splitlines()), kernel
+
+
+def test_prompt_page_write_updates_the_pool_in_place(one_chip):
+    """The placement program at the benchmark cell's shapes: a pool of
+    515 pages of 16 tokens (k and v) and a (2, 2048) prefill's K/V.
+    Both pool outputs alias the donated inputs, and the temporaries stay
+    under a tenth of one pool: no second pool, no reordered K/V copy."""
+    pool = (CFG.n_layers, 515, PAGE, CFG.n_kv_heads, CFG.head_dim)
+    new = (CFG.n_layers, 2, 2048, CFG.n_kv_heads, CFG.head_dim)
+    args = ((_spec(pool, jnp.float32, one_chip),) * 2,
+            _spec(new, jnp.float32, one_chip),
+            _spec(new, jnp.float32, one_chip),
+            _spec((2, 2048 // PAGE), jnp.int32, one_chip))
+    compiled = jax.jit(api.write_prompt_pages, donate_argnums=(0,)).lower(
+        *args).compile()
+    alias = re.search(r"input_output_alias=\{ \{0\}: \(0, \{\}, [a-z-]+\), "
+                      r"\{1\}: \(1, \{\}, [a-z-]+\) \}", compiled.as_text())
+    assert alias, "the pool outputs do not alias the donated pool"
+    pool_bytes = 4 * int(np.prod(pool))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 10, mem
