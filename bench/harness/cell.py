@@ -17,7 +17,6 @@
 from __future__ import annotations
 
 import gc
-import importlib.util
 import json
 import shutil
 import tempfile
@@ -183,8 +182,7 @@ def reference_gaps(cf, w, adapters, logs, rng, max_out: int,
     import jax
     import jax.numpy as jnp
 
-    ref = _load_module(Path(__file__).resolve().parents[1] / "references"
-                       / f"{cf.reference}.py", f"bench_ref_{cf.reference}")
+    ref = model.reference(cf.dims)
     done = [g for g in logs if g.finished and g.tokens]
     longest = max(range(len(done)), default=None, key=lambda i: (
         len(done[i].item.prompt) + len(done[i].tokens), len(done[i].tokens)))
@@ -225,13 +223,6 @@ def reference_gaps(cf, w, adapters, logs, rng, max_out: int,
         cg = np.concatenate(cgaps).astype(np.float64)
         out.update(control_gaps=cg, control=gap_numbers(cg))
     return out
-
-
-def _load_module(path: Path, name: str):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _pct(values, q: float) -> float:
@@ -446,8 +437,8 @@ def per_layer(root: Path, bench: dict, workload: str, data: RunData) -> dict:
     for m in bench["per_layer"]:
         if "workloads" in m and workload not in m["workloads"]:
             continue
-        mod = _load_module(root / "bench" / "metrics" / f"{m['name']}.py",
-                           f"bench_metric_{m['name']}")
+        mod = model.load_module(root / "bench" / "metrics" / f"{m['name']}.py",
+                                f"bench_metric_{m['name']}")
         v = mod.read(data)
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}

@@ -9,7 +9,7 @@ adapter's true rank, not the arena's padded ``r_max``; KV is float32
 """
 from __future__ import annotations
 
-from .model import TARGETS, Dims
+from .model import TARGETS, Dims, reference
 
 KV_BYTES = 4
 ADAPTER_BYTES = 2
@@ -17,10 +17,8 @@ ACT_BYTES = 4
 
 
 def dense_params(d: Dims, head: bool = True) -> int:
-    """Weights one token multiplies through (per forward pass)."""
-    per_layer = (d.d_model * (d.q_dim + 2 * d.kv_dim) + d.q_dim * d.d_model
-                 + 3 * d.d_model * d.d_ff)
-    return d.n_layers * per_layer + (d.d_model * d.vocab if head else 0)
+    """``matmul_params`` of ``d``'s reference, by the name tests pin."""
+    return reference(d).matmul_params(d, head)
 
 
 def lora_flops_per_token(d: Dims, rank: int) -> int:
@@ -37,15 +35,15 @@ def prefill_flops(d: Dims, start: int, n: int, rank: int) -> int:
     matmuls and LoRA per token, causal attention, and the head at the
     last position only (the engine needs only its logits)."""
     keys = n * start + n * (n + 1) // 2
-    return (2 * dense_params(d, head=False) * n
+    return (2 * reference(d).matmul_params(d, False) * n
             + lora_flops_per_token(d, rank) * n
             + attn_flops(d, 1) * keys + 2 * d.d_model * d.vocab)
 
 
 def decode_flops(d: Dims, n_keys: int, rank: int) -> int:
     """One decoded token that attends over ``n_keys`` keys."""
-    return (2 * dense_params(d) + lora_flops_per_token(d, rank)
-            + attn_flops(d, n_keys))
+    return (2 * reference(d).matmul_params(d, True)
+            + lora_flops_per_token(d, rank) + attn_flops(d, n_keys))
 
 
 def paged_attention_cost(d: Dims, n_keys: int) -> tuple[int, int]:

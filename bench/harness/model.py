@@ -6,10 +6,21 @@ is served in, and hands it to the program; the reference
 (``bench/references``) reads the same arrays. Parameter names are the
 program's flat layout (``repro.models.base.param_shapes``): that is the
 program's input format, not something the program made.
+
+A config file holds ``registry``, ``reference``, the published ``config``
+as run and ``serving`` (``engine`` settings need an ``engine_why``), and
+may hold ``reference_options`` and ``program``: fields no other key
+states, each with a reason in ``program_why``. A family's shapes and
+counts live in ``bench/references/<reference>.py``, which imports nothing
+of the program and provides ``logits_at(w, adapter, tokens, pos, *, dims,
+dtype)``, ``weight_shapes(dims) -> {flat name: shape}`` and
+``matmul_params(dims, head) -> int``, the weights a token multiplies by.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib.util
 import json
 from pathlib import Path
 
@@ -22,26 +33,51 @@ CONFIG_KEYS = {
     "head_dim": "head_dim", "intermediate_size": "d_ff",
     "vocab_size": "vocab_size", "rope_theta": "rope_theta",
     "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
+    "num_experts": "n_experts", "num_experts_per_tok": "top_k",
+    "moe_intermediate_size": "d_ff_expert", "decoder_sparse_step": "moe_every",
 }
 
 #: The LoRA-adapted projections and their (d_in, d_out).
 TARGETS = ("q", "k", "v", "o")
 
+#: Where ``reference`` finds a family's module; a test may repoint it.
+REFERENCES = Path(__file__).resolve().parents[1] / "references"
 
-@dataclasses.dataclass(frozen=True)
+
+def _freeze(v):
+    """A JSON value made hashable: objects as sorted pairs, lists as tuples."""
+    v = sorted(v.items()) if isinstance(v, dict) else v
+    return tuple(map(_freeze, v)) if isinstance(v, (list, tuple)) else v
+
+
+@dataclasses.dataclass(frozen=True, init=False)
 class Dims:
-    """The sizes the benchmark itself needs (weights, reference, FLOPs)."""
+    """The sizes every GQA family shares, the reference's name and, read
+    as attributes from ``extra``, every other key of ``config`` (under its
+    ``CONFIG_KEYS`` name, if any) and of ``reference_options``."""
     n_layers: int
     d_model: int
     n_heads: int
     n_kv_heads: int
     head_dim: int
-    d_ff: int
     vocab: int
     rope_theta: float
     norm_eps: float
-    qk_norm: bool
     tied: bool
+    reference: str
+    extra: tuple
+
+    def __init__(self, *, reference: str = "dense_gqa", **kw):
+        for f in dataclasses.fields(self)[:-2]:  # not reference, extra
+            object.__setattr__(self, f.name, kw.pop(f.name))
+        object.__setattr__(self, "reference", reference)
+        object.__setattr__(self, "extra", _freeze(kw))
+
+    def __getattr__(self, name):
+        for k, v in self.__dict__.get("extra", ()):
+            if k == name:
+                return v
+        raise AttributeError(name)
 
     @property
     def q_dim(self) -> int:
@@ -58,6 +94,21 @@ class Dims:
                 "o": (self.q_dim, self.d_model)}[t]
 
 
+@functools.cache
+def load_module(path: Path, name: str):
+    """The Python file ``path`` as a module, loaded once per process."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference(d: Dims):
+    """The reference module ``d`` names, from ``REFERENCES``."""
+    return load_module(REFERENCES / f"{d.reference}.py",
+                       f"bench_ref_{d.reference}")
+
+
 class ConfigFile:
     """``bench/configs/<config>.json``: the configuration as it is run,
     its source, what was cut and why, and the engine's sizes."""
@@ -65,56 +116,52 @@ class ConfigFile:
     def __init__(self, path: Path):
         self.path = Path(path)
         self.data = json.loads(self.path.read_text())
-        c = self.data["config"]
+        opts = self.data.get("reference_options", {})
+        prog, why = self.data.get("program", {}), self.data.get("program_why")
+        stated = {*CONFIG_KEYS, *CONFIG_KEYS.values(), *self.data["config"],
+                  *(f.name for f in dataclasses.fields(Dims))}
+        for what, bad in (("reference_options restates", set(opts) & stated),
+                          ("program restates", set(prog) & (stated | {*opts})),
+                          ("no program_why for", set(prog) - set(why or ()))):
+            if bad:
+                raise ValueError(f"{self.path}: {what} {sorted(bad)}")
+        c = dict(self.data["config"])
+        D, H = c.pop("hidden_size"), c.pop("num_attention_heads")
         self.dims = Dims(
-            n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-            n_heads=c["num_attention_heads"],
-            n_kv_heads=c["num_key_value_heads"],
-            head_dim=c.get("head_dim",
-                           c["hidden_size"] // c["num_attention_heads"]),
-            d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-            rope_theta=float(c["rope_theta"]),
-            norm_eps=float(c["rms_norm_eps"]),
-            qk_norm=bool(self.data["reference_options"]["qk_norm"]),
-            tied=bool(c.get("tie_word_embeddings", False)))
+            n_layers=c.pop("num_hidden_layers"), d_model=D, n_heads=H,
+            n_kv_heads=c.pop("num_key_value_heads"),
+            head_dim=c.pop("head_dim", D // H), vocab=c.pop("vocab_size"),
+            rope_theta=float(c.pop("rope_theta")),
+            norm_eps=float(c.pop("rms_norm_eps")),
+            tied=bool(c.pop("tie_word_embeddings", False)),
+            reference=self.data["reference"],
+            **{CONFIG_KEYS.get(k, k): v for k, v in c.items()}, **opts)
         self.serving = self.data["serving"]
-        self.reference = self.data["reference"]
 
     def model_config(self):
-        """The program's ModelConfig: its registry entry with every
-        number this file states."""
+        """The program's ModelConfig: its registry entry with the keys
+        ``CONFIG_KEYS`` maps, the reference options that are also program
+        fields (one statement for both sides), and ``program``."""
         from repro.configs import get_config
+        base = get_config(self.data["registry"])
+        fields = {f.name for f in dataclasses.fields(base)}
         c = self.data["config"]
         kw = {f: c[k] for k, f in CONFIG_KEYS.items() if k in c}
         kw["head_dim"] = self.dims.head_dim
-        kw["qk_norm"] = self.dims.qk_norm
-        return dataclasses.replace(get_config(self.data["registry"]), **kw)
-
-
-def weight_shapes(d: Dims) -> dict[str, tuple]:
-    L, D, F = d.n_layers, d.d_model, d.d_ff
-    s = {"embed/tok": (d.vocab, D), "final_norm": (D,),
-         "layers/attn_norm": (L, D), "layers/q": (L, D, d.q_dim),
-         "layers/k": (L, D, d.kv_dim), "layers/v": (L, D, d.kv_dim),
-         "layers/o": (L, d.q_dim, D), "layers/mlp_norm": (L, D),
-         "layers/gate": (L, D, F), "layers/up": (L, D, F),
-         "layers/down": (L, F, D)}
-    if not d.tied:
-        s["lm_head"] = (D, d.vocab)
-    if d.qk_norm:
-        s["layers/q_norm"] = (L, d.head_dim)
-        s["layers/k_norm"] = (L, d.head_dim)
-    return s
+        kw.update((k, v) for k, v in self.data.get(
+            "reference_options", {}).items() if k in fields)
+        kw.update(self.data.get("program", {}))
+        return dataclasses.replace(base, **kw)
 
 
 def make_weights(d: Dims, key, dtype: str = "float32") -> dict:
-    """Every weight, from ``key``, in one jitted program on the device.
-    Matrices are N(0, 1/fan_in); the embedding N(0, 1); norm gains
-    1 + N(0, 0.1^2), so a norm that drops its gain shows."""
+    """Every weight the reference's ``weight_shapes`` names, from ``key``,
+    in one jitted program on the device. Matrices are N(0, 1/fan_in); the
+    embedding N(0, 1); norm gains 1 + N(0, 0.1^2), so a dropped gain shows."""
     import jax
     import jax.numpy as jnp
 
-    shapes = weight_shapes(d)
+    shapes = reference(d).weight_shapes(d)
     names = sorted(shapes)
 
     def init(key):

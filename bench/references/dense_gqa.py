@@ -45,6 +45,30 @@ def _rope(x, pos, theta):
                            -1).astype(x.dtype)
 
 
+def weight_shapes(d) -> dict[str, tuple]:
+    """The weights the harness makes, under the program's flat names."""
+    L, D, F = d.n_layers, d.d_model, d.d_ff
+    s = {"embed/tok": (d.vocab, D), "final_norm": (D,),
+         "layers/attn_norm": (L, D), "layers/q": (L, D, d.q_dim),
+         "layers/k": (L, D, d.kv_dim), "layers/v": (L, D, d.kv_dim),
+         "layers/o": (L, d.q_dim, D), "layers/mlp_norm": (L, D),
+         "layers/gate": (L, D, F), "layers/up": (L, D, F),
+         "layers/down": (L, F, D)}
+    if not d.tied:
+        s["lm_head"] = (D, d.vocab)
+    if d.qk_norm:
+        s["layers/q_norm"] = (L, d.head_dim)
+        s["layers/k_norm"] = (L, d.head_dim)
+    return s
+
+
+def matmul_params(d, head: bool) -> int:
+    """Weights a token multiplies through: q, k, v, o, gated MLP, head."""
+    per_layer = (d.d_model * (d.q_dim + 2 * d.kv_dim) + d.q_dim * d.d_model
+                 + 3 * d.d_model * d.d_ff)
+    return d.n_layers * per_layer + (d.d_model * d.vocab if head else 0)
+
+
 @partial(jax.jit, static_argnames=("dims", "dtype"))
 def logits_at(w, adapter, tokens, pos, *, dims, dtype):
     """Logits (N, V) at positions ``pos`` (N,) of ``tokens`` (T,).

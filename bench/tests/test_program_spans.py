@@ -11,7 +11,7 @@ from types import SimpleNamespace
 import pytest
 
 import bench.harness.cell as cell
-from bench.harness import program_spans, trace
+from bench.harness import model, program_spans, trace
 from bench.harness.trace import Event, Trace
 from bench.tests import tiny
 from repro.serving.spans import SPANS, Span
@@ -21,7 +21,7 @@ READERS = ("adapter_wait_p90_s", "adapter_stall_idle_share",
 
 
 def reader(name):
-    return cell._load_module(tiny.BENCH / "metrics" / f"{name}.py",
+    return model.load_module(tiny.BENCH / "metrics" / f"{name}.py",
                              f"test_metric_{name}")
 
 
